@@ -639,8 +639,10 @@ def bench_monitor_overhead():
     hook_us = (_t.perf_counter() - t0) / n * 1e6
 
     cfg = reduced_config("llsc-100m")
+    # the host CPU has no published peak; use the hook row's nominal one
     t = Trainer(cfg, TrainerConfig(steps=10, batch_size=4, seq_len=64,
-                                   log_every=0, monitor_every=1))
+                                   log_every=0, monitor_every=1,
+                                   peak_flops=1e12))
     t.run(resume=False)
     step_us = np.median([h["time_s"] for h in t.history[2:]]) * 1e6
     _row("monitor_overhead", hook_us,
@@ -844,6 +846,9 @@ def main(argv=None) -> None:
         raise SystemExit(
             f"unknown benchmark(s) {', '.join(unknown)}; "
             f"valid: {', '.join(sorted(names))}")
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     print("name,us_per_call,derived")
     for bench in (BENCHES if not picked else [names[p] for p in picked]):
         bench()

@@ -2,12 +2,14 @@
 steps WITH LLload self-reporting, checkpoint/restart and straggler hooks.
 
     PYTHONPATH=src python examples/train_with_monitoring.py \
-        [--steps 240] [--quick] [--crash-at N]
+        [--steps 240] [--quick] [--crash-at N] [--peak-flops F]
 
 ``--quick`` uses the reduced config (CI-speed); the default trains the full
 110M model on CPU (batch 4 x seq 64; a few seconds per step).  While
 training, the job is visible to LLload exactly like a user job at LLSC:
 its duty cycle, memory and step times flow through the collector registry.
+The duty cycle divides by the device's published peak (``roofline.hw``); the
+CPU has none, so pass one there (``--peak-flops 5e10``).
 """
 import argparse
 
@@ -25,6 +27,9 @@ def main():
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--crash-at", type=int, default=None)
     ap.add_argument("--ckpt-dir", default="/tmp/llsc100m-ckpt")
+    ap.add_argument("--peak-flops", type=float, default=None,
+                    help="device peak FLOP/s for the duty cycle (needed "
+                         "where roofline.hw has no entry, e.g. the CPU)")
     args = ap.parse_args()
 
     cfg = get_config("llsc-100m")
@@ -33,6 +38,7 @@ def main():
     tcfg = TrainerConfig(steps=args.steps, batch_size=args.batch,
                          seq_len=args.seq, ckpt_dir=args.ckpt_dir,
                          ckpt_every=40, log_every=10,
+                         peak_flops=args.peak_flops,
                          job_name=f"train:{cfg.name}")
     crash = CrashInjector(args.crash_at) if args.crash_at else None
     trainer = Trainer(cfg, tcfg, crash=crash)

@@ -19,6 +19,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 F32 = jnp.float32
 NEG_INF = -1e30
@@ -86,13 +87,6 @@ def flash_attention(q, k, v, *, causal: bool = True, scale=None,
         _flash_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, n_kv_blocks=nk)
 
-    from jax.experimental.pallas import tpu as pltpu
-
-    # jax renamed TPUCompilerParams -> CompilerParams across releases; accept
-    # whichever this jax build provides.
-    _COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-
     grid = (B, H, nq, nk)
     return pl.pallas_call(
         kernel,
@@ -112,7 +106,7 @@ def flash_attention(q, k, v, *, causal: bool = True, scale=None,
             pltpu.VMEM((block_q,), F32),
             pltpu.VMEM((block_q, D), F32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -32,46 +32,48 @@ def _gated_kernel(y_ref, z_ref, s_ref, o_ref, *, eps: float):
     o_ref[...] = o.astype(o_ref.dtype)
 
 
-def _rows_call(kernel, args, rows, d, dtype, block_rows, interpret):
-    n = rows // block_rows
-    in_specs = [pl.BlockSpec((block_rows, d), lambda i: (i, 0))
-                for _ in range(len(args) - 1)]
-    in_specs.append(pl.BlockSpec((d,), lambda i: (0,)))  # scale
-    return pl.pallas_call(
+def _row_tiles(rows: int, block_rows: int):
+    """Rows per tile and the padded row count.
+
+    Mosaic tiles the row axis in sublanes of 8, so a tile is a multiple of 8
+    rows; the rows are padded up to whole tiles instead of shrinking the tile
+    to a divisor of ``rows`` (300 rows would otherwise give 150-row tiles).
+    """
+    n_tiles = -(-rows // block_rows)
+    tile = -(-rows // n_tiles)
+    tile = -(-tile // 8) * 8
+    return tile, n_tiles * tile
+
+
+def _rows_call(kernel, arrays, scale, block_rows, interpret):
+    """Apply a row-wise kernel to ``arrays`` [..., D] (all the same shape)."""
+    shape = arrays[0].shape
+    d = shape[-1]
+    rows = math.prod(shape[:-1])
+    tile, padded = _row_tiles(rows, block_rows)
+    args = [jnp.pad(a.reshape(rows, d), ((0, padded - rows), (0, 0)))
+            for a in arrays]
+    row_spec = pl.BlockSpec((tile, d), lambda i: (i, 0))
+    out = pl.pallas_call(
         kernel,
-        grid=(n,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, d), dtype),
+        grid=(padded // tile,),
+        in_specs=[row_spec] * len(args) + [pl.BlockSpec((d,), lambda i: (0,))],
+        out_specs=row_spec,
+        out_shape=jax.ShapeDtypeStruct((padded, d), arrays[0].dtype),
         interpret=interpret,
-    )(*args)
+    )(*args, scale)
+    return out[:rows].reshape(shape)
 
 
 def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: int = 256,
             interpret: bool = False):
     """x [..., D]; scale [D]."""
-    shape = x.shape
-    d = shape[-1]
-    rows = math.prod(shape[:-1])
-    x2 = x.reshape(rows, d)
-    block_rows = min(block_rows, rows)
-    while rows % block_rows:
-        block_rows -= 1
-    out = _rows_call(functools.partial(_rmsnorm_kernel, eps=eps),
-                     (x2, scale), rows, d, x.dtype, block_rows, interpret)
-    return out.reshape(shape)
+    return _rows_call(functools.partial(_rmsnorm_kernel, eps=eps), (x,),
+                      scale, block_rows, interpret)
 
 
 def gated_rmsnorm(y, z, scale, *, eps: float = 1e-5, block_rows: int = 256,
                   interpret: bool = False):
     """RMSNorm(y * silu(z)); y,z [..., D]; scale [D]."""
-    shape = y.shape
-    d = shape[-1]
-    rows = math.prod(shape[:-1])
-    block_rows = min(block_rows, rows)
-    while rows % block_rows:
-        block_rows -= 1
-    out = _rows_call(functools.partial(_gated_kernel, eps=eps),
-                     (y.reshape(rows, d), z.reshape(rows, d), scale),
-                     rows, d, y.dtype, block_rows, interpret)
-    return out.reshape(shape)
+    return _rows_call(functools.partial(_gated_kernel, eps=eps), (y, z),
+                      scale, block_rows, interpret)

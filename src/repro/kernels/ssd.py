@@ -12,6 +12,9 @@ mirroring the grouped layout the pure-jnp path uses.
 
 The inter-chunk recurrence stays in jnp (tiny, bandwidth-trivial scan);
 this kernel covers the FLOP-dominant quadratic term.
+
+It runs in interpret mode only: it does not compile for TPU v5e, since
+Mosaic has no lowering for ``cumsum`` (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 

@@ -36,13 +36,6 @@ from repro.train.train_step import (default_opt_cfg,  # noqa: E402
                                     init_train_state_shape, make_train_step)
 
 
-def _use_mesh(mesh):
-    try:
-        return jax.sharding.use_mesh(mesh)
-    except AttributeError:  # older jax: Mesh as context manager
-        return mesh
-
-
 # --------------------------------------------------------------------------
 # Step builders: (fn, example_args, in_shardings, donate_argnums)
 # --------------------------------------------------------------------------
@@ -98,7 +91,7 @@ def _compile_cell(cfg, shape, mesh, *, unroll: bool):
     from repro.models.scan_util import unroll_scans
 
     ctx = unroll_scans() if unroll else nullcontext()
-    with _use_mesh(mesh), hint_context(mesh), ctx:
+    with jax.set_mesh(mesh), hint_context(mesh), ctx:
         fn, args, shardings, donate = build_cell(cfg, shape, mesh)
         jfn = jax.jit(fn, in_shardings=shardings, donate_argnums=donate)
         lowered = jfn.lower(*args)

@@ -6,7 +6,9 @@ monitoring and overload-aware admission.
 
 The engine publishes per-step duty cycle into the LLload registry; at the
 end it prints the LLload view of itself plus the controller's NPPN verdict
-(the paper's overloading loop applied to this very job).
+(the paper's overloading loop applied to this very job).  On a device that
+``repro.roofline.hw`` lists the duty uses the published peak; elsewhere (the
+CPU) pass ``--peak-flops``.
 """
 from __future__ import annotations
 
@@ -17,6 +19,7 @@ import numpy as np
 
 from repro.configs import get_config, reduced_config
 from repro.core.collector import JaxJobRegistry
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_params
 from repro.serve.engine import EngineConfig, Request, ServeEngine
 
@@ -31,7 +34,12 @@ def main(argv=None) -> int:
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-seq", type=int, default=128)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--peak-flops", type=float, default=None,
+                    help="device peak FLOP/s for the duty cycle (needed "
+                         "where roofline.hw has no entry, e.g. the CPU)")
     args = ap.parse_args(argv)
+
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -39,7 +47,7 @@ def main(argv=None) -> int:
     params = init_params(cfg, jax.random.PRNGKey(args.seed))
     eng = ServeEngine(cfg, params, EngineConfig(
         slots=args.slots, max_seq_len=args.max_seq,
-        job_name=f"serve:{cfg.name}"))
+        peak_flops=args.peak_flops, job_name=f"serve:{cfg.name}"))
 
     rng = np.random.default_rng(args.seed)
     for i in range(args.requests):

@@ -1,35 +1,20 @@
-"""Training launcher CLI.
+"""Training launcher CLI (one device: the trainer builds no mesh).
 
     PYTHONPATH=src python -m repro.launch.train --arch llsc-100m \
         --steps 200 --batch 8 --seq 256 [--reduced] [--ckpt-dir ckpts/run1]
 
-On this CPU container full-size archs are launched with --reduced (same
-family/pattern, tiny dims); on a real pod the same entrypoint builds the
-production mesh and shards via repro.launch.sharding.
-
-XLA flags for a real TPU run (latency-hiding overlap of the gradient
-collectives with backward compute) are recorded here so the launcher is the
-single source of truth:
-
-    --xla_tpu_enable_async_collective_fusion=true
-    --xla_tpu_enable_async_collective_fusion_fuse_all_gather=true
-    --xla_tpu_overlap_compute_collective_tc=true
-    --xla_enable_async_all_gather=true
+``--reduced`` runs the same family and layer pattern at tiny widths, for the
+CPU.  On a device that ``repro.roofline.hw`` lists, the duty proxy takes the
+published peak; elsewhere (the CPU) pass ``--peak-flops``.
 """
 from __future__ import annotations
 
 import argparse
 
 from repro.configs import get_config, reduced_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.fault import CrashInjector
 from repro.train.trainer import Trainer, TrainerConfig
-
-TPU_XLA_FLAGS = (
-    "--xla_tpu_enable_async_collective_fusion=true "
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true "
-    "--xla_tpu_overlap_compute_collective_tc=true "
-    "--xla_enable_async_all_gather=true"
-)
 
 
 def main(argv=None) -> int:
@@ -45,7 +30,12 @@ def main(argv=None) -> int:
     ap.add_argument("--crash-at", type=int, default=None,
                     help="inject a failure at this step (restart demo)")
     ap.add_argument("--no-resume", action="store_true")
+    ap.add_argument("--peak-flops", type=float, default=None,
+                    help="device peak FLOP/s for the duty proxy (needed "
+                         "where roofline.hw has no entry, e.g. the CPU)")
     args = ap.parse_args(argv)
+
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -53,6 +43,7 @@ def main(argv=None) -> int:
     tcfg = TrainerConfig(steps=args.steps, batch_size=args.batch,
                          seq_len=args.seq, ckpt_dir=args.ckpt_dir,
                          ckpt_every=args.ckpt_every,
+                         peak_flops=args.peak_flops,
                          job_name=f"train:{cfg.name}")
     crash = CrashInjector(args.crash_at) if args.crash_at else None
     trainer = Trainer(cfg, tcfg, crash=crash)

@@ -131,13 +131,11 @@ def moe_ffn_a2a(params, x, spec, act, mesh, *, fsdp_axes, tp_axis="model"):
     Requires S % tp == 0, E % tp == 0, B % fsdp == 0; the caller falls back
     to the GSPMD path otherwise.
     """
-    from jax.experimental.shard_map import shard_map
-
     tp_size = mesh.shape[tp_axis]
     e_loc = spec.n_experts // tp_size
     blk = partial(_moe_block, spec=spec, act=act, tp_size=tp_size,
                   e_loc=e_loc, axis_name=tp_axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         blk, mesh=mesh,
         in_specs=(P(fsdp_axes, tp_axis, None),   # x: tokens over fsdp x tp
                   P(None, None),                 # router (replicated)
@@ -145,7 +143,7 @@ def moe_ffn_a2a(params, x, spec, act, mesh, *, fsdp_axes, tp_axis="model"):
                   P(tp_axis, None, None),        # w3
                   P(tp_axis, None, None)),       # w2
         out_specs=P(fsdp_axes, tp_axis, None),
-        check_rep=False)
+        check_vma=False)
     return fn(x, params["router"].astype(x.dtype), params["w1"],
               params["w3"], params["w2"])
 

@@ -50,7 +50,9 @@ class EngineConfig:
     top_k: int = 0                # 0 = full distribution
     seed: int = 0
     job_name: str = "serve"
-    peak_flops: float = 5e10
+    # peak FLOP/s of the local device, for the duty cycle.  None takes it
+    # from roofline.hw's table, which raises for a device it does not list.
+    peak_flops: Optional[float] = None
     monitor: bool = True
 
 
@@ -64,6 +66,8 @@ class ServeEngine:
         self.queue: deque = deque()
         self.completions: List[Completion] = []
         self.controller = OverloadController()
+        self.peak_flops = (hw.resolve_peak_flops(ecfg.peak_flops)
+                           if ecfg.monitor else None)
         self._decode = jax.jit(
             lambda p, t, c, l: model_lib.decode_step(p, cfg, t, c, l),
             donate_argnums=(2,))
@@ -183,11 +187,11 @@ class ServeEngine:
                 achieved = self._flops_per_token * n_active
                 publish_step_utilization(
                     ecfg.job_name, model_flops_per_step=achieved,
-                    step_time_s=dt, peak_flops=ecfg.peak_flops,
+                    step_time_s=dt, peak_flops=self.peak_flops,
                     n_devices=jax.device_count(),
                     hbm_total_gb=hw.HBM_BYTES / 1e9)
                 self.controller.observe(DeviceObservation(
-                    duty_cycle=min(1.0, achieved / (dt * ecfg.peak_flops)),
+                    duty_cycle=min(1.0, achieved / (dt * self.peak_flops)),
                     mem_used_gb=0.1 * n_active, mem_total_gb=16.0))
 
         wall = time.perf_counter() - t_start

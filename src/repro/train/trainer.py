@@ -39,9 +39,9 @@ class TrainerConfig:
     log_every: int = 10
     seed: int = 0
     job_name: str = "train"
-    # peak FLOP/s of the *local* device, for the duty-cycle proxy.  On CPU we
-    # calibrate a nominal peak so utilization numbers are meaningful.
-    peak_flops: float = 5e10
+    # peak FLOP/s of the local device, for the duty-cycle proxy.  None takes
+    # it from roofline.hw's table, which raises for a device it does not list.
+    peak_flops: Optional[float] = None
 
 
 class Trainer:
@@ -55,6 +55,8 @@ class Trainer:
         self.step_fn = jax.jit(make_train_step(cfg, self.opt_cfg),
                                donate_argnums=(0,))
         self.crash = crash
+        self.peak_flops = (hw.resolve_peak_flops(tcfg.peak_flops)
+                           if tcfg.monitor_every else None)
         self.straggler = StragglerDetector()
         self.host = socket.gethostname()
         self.history: list = []
@@ -105,7 +107,7 @@ class Trainer:
                 publish_step_utilization(
                     tc.job_name,
                     model_flops_per_step=self._flops_per_step,
-                    step_time_s=dt, peak_flops=tc.peak_flops,
+                    step_time_s=dt, peak_flops=self.peak_flops,
                     n_devices=jax.device_count(),
                     hbm_used_gb=params_bytes / 1e9,
                     hbm_total_gb=hw.HBM_BYTES * jax.device_count() / 1e9)

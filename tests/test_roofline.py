@@ -56,3 +56,15 @@ def test_hw_constants():
     assert hw.PEAK_FLOPS_BF16 == 197e12
     assert hw.HBM_BW == 819e9
     assert hw.ICI_BW_PER_LINK == 50e9
+
+
+def test_peak_table_keyed_by_device_kind():
+    v5e = hw.chip_peak("TPU v5 lite")
+    assert (v5e.flops_bf16, v5e.hbm_bw, v5e.hbm_bytes) == \
+        (197e12, 819e9, 16 * 1024 ** 3)
+    with pytest.raises(ValueError, match="no published peak"):
+        hw.chip_peak("cpu")
+    assert hw.resolve_peak_flops(3e11) == 3e11
+    # this test process runs on the CPU, which the table does not list
+    with pytest.raises(ValueError, match="pass peak_flops explicitly"):
+        hw.resolve_peak_flops(None)

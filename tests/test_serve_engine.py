@@ -8,6 +8,8 @@ from repro.models import init_params
 from repro.serve.engine import EngineConfig, Request, ServeEngine
 
 KEY = jax.random.PRNGKey(0)
+# The CPU has no published peak, so monitored engines are given one.
+CPU_PEAK_FLOPS = 5e10
 
 
 def _engine(arch="llsc-100m", slots=2, max_seq=64):
@@ -15,7 +17,8 @@ def _engine(arch="llsc-100m", slots=2, max_seq=64):
     params = init_params(cfg, KEY)
     return cfg, ServeEngine(cfg, params,
                             EngineConfig(slots=slots, max_seq_len=max_seq,
-                                         monitor=True))
+                                         monitor=True,
+                                         peak_flops=CPU_PEAK_FLOPS))
 
 
 def _req(i, n=6, prompt_len=8, vocab=512):
@@ -75,3 +78,13 @@ def test_throughput_reported():
     stats = eng.run()
     assert stats["tokens_per_s"] > 0
     assert stats["tokens"] >= stats["requests"]
+
+
+def test_monitored_engine_needs_a_known_peak():
+    """A device missing from roofline.hw's table has no default peak."""
+    cfg = reduced_config("llsc-100m")
+    params = init_params(cfg, KEY)
+    with pytest.raises(ValueError, match="no published peak"):
+        ServeEngine(cfg, params, EngineConfig(monitor=True))
+    eng = ServeEngine(cfg, params, EngineConfig(monitor=False))
+    assert eng.peak_flops is None

@@ -40,7 +40,7 @@ def _snaps(n, t0=1_700_000_000.0, step=300.0):
 # ------------------------------------------------------------ record format
 
 
-@given(st.lists(st.tuples(
+@given(records=st.lists(st.tuples(
     st.floats(allow_nan=False, allow_infinity=False, width=64),
     st.binary(min_size=0, max_size=200)), min_size=0, max_size=30))
 def test_segment_roundtrip_property(records, tmp_path_factory):
@@ -57,8 +57,8 @@ def test_segment_roundtrip_property(records, tmp_path_factory):
     assert scan.valid_bytes == os.path.getsize(path)
 
 
-@given(st.binary(min_size=1, max_size=64),
-       st.integers(min_value=1, max_value=20))
+@given(payload=st.binary(min_size=1, max_size=64),
+       cut=st.integers(min_value=1, max_value=20))
 def test_torn_tail_truncation_property(payload, cut, tmp_path_factory):
     """Cutting any number of bytes off the final frame loses only that
     frame: every earlier record scans back intact."""

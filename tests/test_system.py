@@ -26,8 +26,10 @@ from repro.train.trainer import Trainer, TrainerConfig
 def test_training_job_visible_to_llload():
     JaxJobRegistry.global_registry().remove("e2e")
     cfg = reduced_config("llsc-100m")
+    # the CPU has no published peak: give the duty proxy a nominal one
     t = Trainer(cfg, TrainerConfig(steps=4, batch_size=2, seq_len=32,
-                                   log_every=0, job_name="e2e"))
+                                   log_every=0, job_name="e2e",
+                                   peak_flops=5e10))
     t.run(resume=False)
     agg = JaxJobRegistry.global_registry().aggregate()
     assert agg.n_devices >= 1
@@ -87,3 +89,12 @@ def test_controller_converges_to_saturation():
                                           mem_total_gb=32.0))
         nppn = ctl.decide(nppn).nppn
     assert nppn == 2  # 0.3 * 2 = 0.6; stepping to 4 would exceed 0.9 target
+
+
+def test_monitored_trainer_needs_a_known_peak():
+    cfg = reduced_config("llsc-100m")
+    with pytest.raises(ValueError, match="no published peak"):
+        Trainer(cfg, TrainerConfig(steps=1, batch_size=2, seq_len=32))
+    t = Trainer(cfg, TrainerConfig(steps=1, batch_size=2, seq_len=32,
+                                   monitor_every=0))
+    assert t.peak_flops is None
