@@ -1,0 +1,9 @@
+"""serve_mfu: the window's serving operations (each admitted prompt's
+prefill, each decoded token over its live context; ``chipbench.flops``)
+over its wall time, as a share of the chip's bf16 peak."""
+
+
+def read(run):
+    if run.kind != "serve":
+        return None
+    return 100.0 * run.flops / run.window_s / run.peak["flops_bf16"]

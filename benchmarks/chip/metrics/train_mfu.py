@@ -1,0 +1,9 @@
+"""train_mfu: the window's training operations (``flops.train_flops_per_token``
+x tokens: forward and backward matmuls and causal attention, no
+recomputation) over its wall time, as a share of the chip's bf16 peak."""
+
+
+def read(run):
+    if run.kind != "train":
+        return None
+    return 100.0 * run.flops / run.window_s / run.peak["flops_bf16"]
