@@ -1,5 +1,5 @@
 """End-to-end driver: train the ~110M `llsc-100m` model for a few hundred
-steps WITH LLload self-reporting, checkpoint/restart and straggler hooks.
+steps WITH LLload self-reporting and checkpoint/restart.
 
     PYTHONPATH=src python examples/train_with_monitoring.py \
         [--steps 240] [--quick] [--crash-at N] [--peak-flops F]

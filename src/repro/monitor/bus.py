@@ -258,6 +258,20 @@ class TelemetryBus:
 # Job-side publish hook (trainer / server -> registry -> live/jobs sources)
 # --------------------------------------------------------------------------
 
+# The profiler spans that ``Trainer.run`` and ``ServeEngine.run`` open at
+# their layer boundaries (``jax.profiler.TraceAnnotation``; the two step
+# spans are ``StepTraceAnnotation``s).  They cost about a microsecond each
+# and record nothing unless a profiler session runs, so they are always on:
+# a job run under ``jax.profiler.trace(dir)`` gets them in its trace.
+SPAN_NAMES = (
+    "llload.train.init", "llload.train.step", "llload.train.feed",
+    "llload.train.dispatch", "llload.train.sync", "llload.train.checkpoint",
+    "llload.serve.init", "llload.serve.step", "llload.serve.admit",
+    "llload.serve.prefill", "llload.serve.first_token", "llload.serve.splice",
+    "llload.serve.decode", "llload.serve.sample", "llload.serve.bookkeep",
+    "llload.monitor.publish",
+)
+
 
 def publish_step_utilization(job_name: str, *, model_flops_per_step: float,
                              step_time_s: float, peak_flops: float,
@@ -268,7 +282,8 @@ def publish_step_utilization(job_name: str, *, model_flops_per_step: float,
     Publishes the step's achieved utilization into the in-process job
     registry (which the ``live`` and ``jobs`` sources read), so jobs
     self-report instead of being probed via privileged ssh+nvidia-smi —
-    the paper's latency complaint, solved at the source.
+    the paper's latency complaint, solved at the source.  Returns the
+    :class:`~repro.core.collector.DeviceUtilization` it published.
     """
     from repro.core.collector import DeviceUtilization, JaxJobRegistry
 
@@ -276,8 +291,10 @@ def publish_step_utilization(job_name: str, *, model_flops_per_step: float,
     if step_time_s > 0 and peak_flops > 0:
         duty = model_flops_per_step / step_time_s / (peak_flops * n_devices)
     reg = registry or JaxJobRegistry.global_registry()
-    reg.publish(job_name, DeviceUtilization(
+    util = DeviceUtilization(
         n_devices=n_devices, n_active=n_devices, duty_cycle=duty,
         hbm_total_gb=hbm_total_gb, hbm_used_gb=hbm_used_gb,
         step_time_s=step_time_s,
-        achieved_flops=model_flops_per_step / max(step_time_s, 1e-9)))
+        achieved_flops=model_flops_per_step / max(step_time_s, 1e-9))
+    reg.publish(job_name, util)
+    return util

@@ -6,6 +6,9 @@ between steps).  The paper tie-in: slot capacity is the NPPN analog —
 the :class:`OverloadController` watches the measured device duty cycle and
 steps the number of concurrent streams 1 -> 2 -> 4 -> 8 exactly like LLSC
 steps tasks-per-GPU, saturating the device with co-resident low-duty work.
+Each step opens the ``llload.serve.*`` profiler spans
+(``repro.monitor.SPAN_NAMES``); the device programs are ``jit_serve_prefill``
+and ``jit_serve_decode``.
 """
 from __future__ import annotations
 
@@ -17,6 +20,7 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.overload import (DeviceObservation, OverloadController,
                                  OverloadDecision)
@@ -68,11 +72,15 @@ class ServeEngine:
         self.controller = OverloadController()
         self.peak_flops = (hw.resolve_peak_flops(ecfg.peak_flops)
                            if ecfg.monitor else None)
-        self._decode = jax.jit(
-            lambda p, t, c, l: model_lib.decode_step(p, cfg, t, c, l),
-            donate_argnums=(2,))
-        self._prefill = jax.jit(
-            lambda p, t: model_lib.prefill(p, cfg, t))
+
+        def serve_decode(params, tokens, caches, lens):
+            return model_lib.decode_step(params, cfg, tokens, caches, lens)
+
+        def serve_prefill(params, tokens):
+            return model_lib.prefill(params, cfg, tokens)
+
+        self._decode = jax.jit(serve_decode, donate_argnums=(2,))
+        self._prefill = jax.jit(serve_prefill)
         self._flops_per_token = model_lib.model_flops(cfg, 1, training=False)
 
     def submit(self, req: Request):
@@ -100,9 +108,12 @@ class ServeEngine:
         token comes from the prefill logits (re-feeding the last prompt
         token through decode would double-update SSM states).
         """
-        tokens = jnp.asarray(req.prompt, jnp.int32)[None]
-        logits, new = self._prefill(self.params, tokens)
-        first_tok = int(self._select(logits, 10_000_000 + req.request_id)[0])
+        with TraceAnnotation("llload.serve.prefill"):
+            tokens = jnp.asarray(req.prompt, jnp.int32)[None]
+            logits, new = self._prefill(self.params, tokens)
+        with TraceAnnotation("llload.serve.first_token"):
+            first_tok = int(self._select(logits,
+                                         10_000_000 + req.request_id)[0])
         S = tokens.shape[1]
 
         def splice(path, dst, src):
@@ -122,15 +133,19 @@ class ServeEngine:
             return dst.at[tuple(idx)].set(
                 src[tuple(src_idx)].astype(dst.dtype))
 
-        caches = jax.tree_util.tree_map_with_path(splice, caches, new)
+        with TraceAnnotation("llload.serve.splice"):
+            caches = jax.tree_util.tree_map_with_path(splice, caches, new)
         return caches, S, first_tok
 
     # ------------------------------------------------------------------
     def run(self, *, max_steps: int = 10_000) -> dict:
-        """Drain the queue.  Returns throughput stats."""
+        """Drain the queue.  Returns throughput stats, with this run's
+        admissions and prompt tokens prefilled, and the mean duty cycle the
+        monitor hook published (None with monitoring off)."""
         cfg, ecfg = self.cfg, self.ecfg
         B, T = ecfg.slots, ecfg.max_seq_len
-        caches = model_lib.init_cache(cfg, B, T)
+        with TraceAnnotation("llload.serve.init"):
+            caches = model_lib.init_cache(cfg, B, T)
         lens = np.zeros(B, np.int32)
         active: List[Optional[Request]] = [None] * B
         outputs: List[List[int]] = [[] for _ in range(B)]
@@ -139,68 +154,87 @@ class ServeEngine:
         t_start = time.perf_counter()
         tokens_out = 0
         steps = 0
+        admitted = prefill_tokens = 0
+        duties = []
         while (self.queue or any(a is not None for a in active)) \
                 and steps < max_steps:
-            # refill free slots
-            for s in range(B):
-                if active[s] is None and self.queue:
-                    req = self.queue.popleft()
-                    caches, S, first = self._prefill_one(req, caches, s, T)
-                    active[s] = req
-                    lens[s] = S
-                    outputs[s] = [first]
-                    last[s] = first
-                    tokens_out += 1
-                    if len(outputs[s]) >= req.max_new_tokens:
-                        self.completions.append(Completion(
-                            req.request_id, outputs[s], len(req.prompt),
-                            time.perf_counter() - req.submitted_s))
-                        active[s] = None
-            if not any(a is not None for a in active):
-                break
+            with StepTraceAnnotation("llload.serve.step", step_num=steps):
+                # refill free slots
+                for s in range(B):
+                    if active[s] is None and self.queue:
+                        req = self.queue.popleft()
+                        with TraceAnnotation("llload.serve.admit",
+                                             request_id=req.request_id,
+                                             prompt_len=len(req.prompt)):
+                            caches, S, first = self._prefill_one(req, caches,
+                                                                 s, T)
+                        admitted += 1
+                        prefill_tokens += S
+                        active[s] = req
+                        lens[s] = S
+                        outputs[s] = [first]
+                        last[s] = first
+                        tokens_out += 1
+                        if len(outputs[s]) >= req.max_new_tokens:
+                            self.completions.append(Completion(
+                                req.request_id, outputs[s], len(req.prompt),
+                                time.perf_counter() - req.submitted_s))
+                            active[s] = None
+                if not any(a is not None for a in active):
+                    break
 
-            t0 = time.perf_counter()
-            # each slot writes its new token at position lens[s]
-            logits, caches = self._decode(
-                self.params, jnp.asarray(last[:, None]), caches,
-                jnp.asarray(lens))
-            nxt = np.asarray(self._select(logits, steps), np.int32)
-            dt = time.perf_counter() - t0
-            steps += 1
+                t0 = time.perf_counter()
+                with TraceAnnotation("llload.serve.decode"):
+                    # each slot writes its new token at position lens[s]
+                    logits, caches = self._decode(
+                        self.params, jnp.asarray(last[:, None]), caches,
+                        jnp.asarray(lens))
+                with TraceAnnotation("llload.serve.sample"):
+                    nxt = np.asarray(self._select(logits, steps), np.int32)
+                dt = time.perf_counter() - t0
+                steps += 1
 
-            n_active = sum(a is not None for a in active)
-            for s in range(B):
-                if active[s] is None:
-                    continue
-                outputs[s].append(int(nxt[s]))
-                last[s] = nxt[s]
-                lens[s] += 1
-                tokens_out += 1
-                req = active[s]
-                if len(outputs[s]) >= req.max_new_tokens or lens[s] >= T:
-                    self.completions.append(Completion(
-                        req.request_id, outputs[s], len(req.prompt),
-                        time.perf_counter() - req.submitted_s))
-                    active[s] = None
+                n_active = sum(a is not None for a in active)
+                with TraceAnnotation("llload.serve.bookkeep"):
+                    for s in range(B):
+                        if active[s] is None:
+                            continue
+                        outputs[s].append(int(nxt[s]))
+                        last[s] = nxt[s]
+                        lens[s] += 1
+                        tokens_out += 1
+                        req = active[s]
+                        if (len(outputs[s]) >= req.max_new_tokens
+                                or lens[s] >= T):
+                            self.completions.append(Completion(
+                                req.request_id, outputs[s], len(req.prompt),
+                                time.perf_counter() - req.submitted_s))
+                            active[s] = None
 
-            if ecfg.monitor:
-                achieved = self._flops_per_token * n_active
-                publish_step_utilization(
-                    ecfg.job_name, model_flops_per_step=achieved,
-                    step_time_s=dt, peak_flops=self.peak_flops,
-                    n_devices=jax.device_count(),
-                    hbm_total_gb=hw.HBM_BYTES / 1e9)
-                self.controller.observe(DeviceObservation(
-                    duty_cycle=min(1.0, achieved / (dt * self.peak_flops)),
-                    mem_used_gb=0.1 * n_active, mem_total_gb=16.0))
+                if ecfg.monitor:
+                    with TraceAnnotation("llload.monitor.publish"):
+                        achieved = self._flops_per_token * n_active
+                        util = publish_step_utilization(
+                            ecfg.job_name, model_flops_per_step=achieved,
+                            step_time_s=dt, peak_flops=self.peak_flops,
+                            n_devices=jax.device_count(),
+                            hbm_total_gb=hw.HBM_BYTES / 1e9)
+                        self.controller.observe(DeviceObservation(
+                            duty_cycle=min(1.0, achieved
+                                           / (dt * self.peak_flops)),
+                            mem_used_gb=0.1 * n_active, mem_total_gb=16.0))
+                    duties.append(util.duty_cycle)
 
         wall = time.perf_counter() - t_start
         return {
             "requests": len(self.completions),
             "tokens": tokens_out,
             "steps": steps,
+            "admitted": admitted,
+            "prefill_tokens": prefill_tokens,
             "wall_s": wall,
             "tokens_per_s": tokens_out / wall if wall > 0 else 0.0,
+            "duty_mean": float(np.mean(duties)) if duties else None,
             "decision": self.controller.decide(ecfg.slots),
         }
 

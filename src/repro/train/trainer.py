@@ -1,23 +1,24 @@
-"""Training loop with LLload self-reporting, checkpoint/restart, straggler
-hooks — the "user job" side of the paper's pipeline.
+"""Training loop with LLload self-reporting and checkpoint/restart — the
+"user job" side of the paper's pipeline.
 
 Every ``monitor_every`` steps the trainer publishes its measured utilization
 (achieved model-FLOP/s over peak => the paper's "GPU load" analog, plus HBM
 use) into the in-process LLload registry; an optional PeriodicArchiver
 captures snapshots on the 15-minute cadence.  The weekly analysis then sees
-this job exactly as LLSC sees a user's GPU job.
+this job exactly as LLSC sees a user's GPU job.  Each step opens the
+``llload.train.*`` profiler spans (``repro.monitor.SPAN_NAMES``).
 """
 from __future__ import annotations
 
 import dataclasses
-import socket
 import time
 from typing import Callable, Optional
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
-from repro.launch.fault import CrashInjector, StragglerDetector
+from repro.launch.fault import CrashInjector
 from repro.monitor import publish_step_utilization
 from repro.models import model as model_lib
 from repro.roofline import hw
@@ -57,8 +58,6 @@ class Trainer:
         self.crash = crash
         self.peak_flops = (hw.resolve_peak_flops(tcfg.peak_flops)
                            if tcfg.monitor_every else None)
-        self.straggler = StragglerDetector()
-        self.host = socket.gethostname()
         self.history: list = []
         # model flops per step (6 N D) for the duty-cycle report
         self._flops_per_step = model_lib.model_flops(
@@ -81,49 +80,63 @@ class Trainer:
         tc = self.tcfg
         start_step = 0
         state = None
-        if tc.ckpt_dir and resume:
-            template = jax.eval_shape(self._init_state)
-            from repro.launch.fault import resume_latest
+        with TraceAnnotation("llload.train.init"):
+            if tc.ckpt_dir and resume:
+                template = jax.eval_shape(self._init_state)
+                from repro.launch.fault import resume_latest
 
-            state, start_step = resume_latest(tc.ckpt_dir, template)
-        if state is None:
-            state = self._init_state()
+                state, start_step = resume_latest(tc.ckpt_dir, template)
+            if state is None:
+                state = self._init_state()
 
         params_bytes = sum(np.prod(x.shape) * x.dtype.itemsize
                            for x in jax.tree.leaves(state))
+        hbm_total_gb = hw.HBM_BYTES * jax.device_count() / 1e9
         losses = []
         for step in range(start_step, tc.steps):
             if self.crash is not None:
                 self.crash.maybe_crash(step)
-            t0 = time.perf_counter()
-            state, metrics = self.step_fn(state, self._batch(step))
-            loss = float(metrics["loss"])  # blocks until step completes
-            dt = time.perf_counter() - t0
-            losses.append(loss)
-            self.straggler.record(self.host, dt)
-            self.history.append({"step": step, "loss": loss, "time_s": dt})
+            with StepTraceAnnotation("llload.train.step", step_num=step):
+                t0 = time.perf_counter()
+                with TraceAnnotation("llload.train.feed"):
+                    batch = self._batch(step)
+                with TraceAnnotation("llload.train.dispatch"):
+                    state, metrics = self.step_fn(state, batch)
+                with TraceAnnotation("llload.train.sync"):
+                    # blocks until the step completes
+                    loss = float(metrics["loss"])
+                dt = time.perf_counter() - t0
+                losses.append(loss)
+                entry = {"step": step, "loss": loss, "time_s": dt,
+                         "duty": None}
+                self.history.append(entry)
 
-            if tc.monitor_every and step % tc.monitor_every == 0:
-                publish_step_utilization(
-                    tc.job_name,
-                    model_flops_per_step=self._flops_per_step,
-                    step_time_s=dt, peak_flops=self.peak_flops,
-                    n_devices=jax.device_count(),
-                    hbm_used_gb=params_bytes / 1e9,
-                    hbm_total_gb=hw.HBM_BYTES * jax.device_count() / 1e9)
-            if tc.log_every and step % tc.log_every == 0:
-                print(f"[train:{self.cfg.name}] step {step} "
-                      f"loss {loss:.4f} ({dt * 1e3:.0f} ms)")
-            if tc.ckpt_dir and tc.ckpt_every and \
-                    (step + 1) % tc.ckpt_every == 0:
-                if tc.async_ckpt:
-                    ckpt_lib.save_checkpoint_async(tc.ckpt_dir, step + 1,
-                                                   state)
-                else:
-                    ckpt_lib.save_checkpoint(tc.ckpt_dir, step + 1, state)
+                if tc.monitor_every and step % tc.monitor_every == 0:
+                    with TraceAnnotation("llload.monitor.publish"):
+                        util = publish_step_utilization(
+                            tc.job_name,
+                            model_flops_per_step=self._flops_per_step,
+                            step_time_s=dt, peak_flops=self.peak_flops,
+                            n_devices=jax.device_count(),
+                            hbm_used_gb=params_bytes / 1e9,
+                            hbm_total_gb=hbm_total_gb)
+                    entry["duty"] = util.duty_cycle
+                if tc.log_every and step % tc.log_every == 0:
+                    print(f"[train:{self.cfg.name}] step {step} "
+                          f"loss {loss:.4f} ({dt * 1e3:.0f} ms)")
+                if tc.ckpt_dir and tc.ckpt_every and \
+                        (step + 1) % tc.ckpt_every == 0:
+                    with TraceAnnotation("llload.train.checkpoint"):
+                        if tc.async_ckpt:
+                            ckpt_lib.save_checkpoint_async(
+                                tc.ckpt_dir, step + 1, state)
+                        else:
+                            ckpt_lib.save_checkpoint(tc.ckpt_dir, step + 1,
+                                                     state)
         if tc.ckpt_dir:
-            ckpt_lib.wait_pending_checkpoints()
-            ckpt_lib.save_checkpoint(tc.ckpt_dir, tc.steps, state)
+            with TraceAnnotation("llload.train.checkpoint"):
+                ckpt_lib.wait_pending_checkpoints()
+                ckpt_lib.save_checkpoint(tc.ckpt_dir, tc.steps, state)
         return {"final_loss": losses[-1] if losses else float("nan"),
                 "losses": losses, "start_step": start_step,
                 "state": state}

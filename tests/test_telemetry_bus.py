@@ -279,3 +279,12 @@ def test_publish_hook_feeds_registry():
     agg = reg.aggregate()
     assert agg.n_devices == 2
     assert agg.duty_cycle == pytest.approx(1e9 / 0.01 / (1e12 * 2))
+
+
+def test_publish_hook_returns_what_it_published():
+    reg = JaxJobRegistry()
+    util = publish_step_utilization("job-b", model_flops_per_step=2e9,
+                                    step_time_s=0.5, peak_flops=1e10,
+                                    registry=reg)
+    assert reg.entries()["job-b"] is util
+    assert util.duty_cycle == pytest.approx(0.4)
