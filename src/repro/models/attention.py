@@ -230,41 +230,63 @@ def gqa_attention(params, x, cfg, *, local: bool, positions, chunk=None,
     return out.reshape(B, S, -1) @ params["wo"], (k, v)
 
 
-def _cache_write(cache, new, cache_len):
-    """Write new [B,1,...] at position cache_len (scalar or per-row [B])."""
-    cache_len = jnp.asarray(cache_len)
-    if cache_len.ndim == 0:
-        return jax.lax.dynamic_update_slice_in_dim(
-            cache, new.astype(cache.dtype), cache_len, axis=1)
-    return jax.vmap(
-        lambda c, n, l: jax.lax.dynamic_update_slice_in_dim(
-            c, n.astype(c.dtype), l, axis=0))(cache, new, cache_len)
+def cache_write(cache, new, lens, layer=None):
+    """Write row b of ``new`` [B,1,...] at (layer, b, lens[b]) of ``cache``.
+
+    ``cache`` is a layer stack [L,B,T,...] when ``layer`` is given and one
+    layer [B,T,...] when it is None.  Each slot is one
+    ``dynamic_update_slice`` of a two-row window (the new row, and its
+    neighbour written back unchanged), so a donated cache is updated in
+    place.  A scatter, a vmap over rows, or a one-row window lets XLA's TPU
+    layout assignment copy and relayout the whole stack (a one-row window
+    does so where the cache's layout puts T minor-most, as for d_head 64).
+    A row at or past T writes nothing.
+    """
+    lead = () if layer is None else (layer,)
+    T = cache.shape[len(lead) + 1]
+    W = min(2, T)
+    win = (1,) * len(lead) + (1, W) + new.shape[2:]
+    hit_shape = (1,) * len(lead) + (1, W) + (1,) * (new.ndim - 2)
+    rest = (0,) * (new.ndim - 2)
+    new = new.astype(cache.dtype)
+    for b in range(new.shape[0]):
+        start = jnp.minimum(lens[b], T - W)
+        at = lead + (b, start) + rest
+        hit = (jnp.arange(W) == lens[b] - start).reshape(hit_shape)
+        row = new[b:b + 1] if layer is None else new[None, b:b + 1]
+        window = jnp.where(hit, row, jax.lax.dynamic_slice(cache, at, win))
+        cache = jax.lax.dynamic_update_slice(cache, window, at)
+    return cache
 
 
-def _decode_positions(cache_len):
-    cache_len = jnp.asarray(cache_len)
-    if cache_len.ndim == 0:
-        return jnp.full((1,), cache_len, dtype=jnp.int32)      # [S=1]
-    return cache_len[:, None].astype(jnp.int32)                # [B,1]
+def cache_layer(cache, layer=None):
+    """One layer of a cache stack [L,...] (the cache itself without a
+    ``layer``).  Read after the write, so the compiler fuses the slice into
+    the attention dot instead of materialising the layer."""
+    if layer is None:
+        return cache
+    return jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
 
 
-def gqa_decode(params, x, cfg, cache_k, cache_v, cache_len, *, local: bool):
-    """Single-token decode. x [B,1,D]; cache_[kv] [B,T,Hk,D] -> out, caches.
+def gqa_decode(params, x, cfg, cache_k, cache_v, lens, *, local: bool,
+               layer=None):
+    """Single-token decode. x [B,1,D]; lens [B]: each row's cache length.
 
-    ``cache_len`` is a scalar (synchronous batch) or per-row [B] vector
-    (continuous batching with ragged slot lengths)."""
+    ``cache_[kv]`` is the layer stack [L,B,T,Hk,D] with ``layer`` its index,
+    or one layer [B,T,Hk,D] without; returns (out, cache_k, cache_v) with
+    the new K/V rows written at ``lens``."""
     q, k, v = gqa_project_qkv(params, x, cfg.n_heads, cfg.n_kv_heads, cfg.d_head)
     theta = cfg.rope_theta_local if (local and cfg.rope_theta_local) \
         else cfg.rope_theta
-    pos = _decode_positions(cache_len)
+    pos = lens[:, None]
     q = apply_rope_bshd(q, pos, theta)
     k = apply_rope_bshd(k, pos, theta)
-    cache_k = _cache_write(cache_k, k, cache_len)
-    cache_v = _cache_write(cache_v, v, cache_len)
+    cache_k = cache_write(cache_k, k, lens, layer)
+    cache_v = cache_write(cache_v, v, lens, layer)
     window = cfg.attn_window if local else None
     out = chunked_attention(
-        q, cache_k, cache_v, causal=True, window=window, q_offset=cache_len,
-        kv_valid_len=jnp.asarray(cache_len) + 1,
+        q, cache_layer(cache_k, layer), cache_layer(cache_v, layer),
+        causal=True, window=window, q_offset=lens, kv_valid_len=lens + 1,
         softcap=cfg.attn_logit_softcap)
     B = x.shape[0]
     return out.reshape(B, 1, -1) @ params["wo"], cache_k, cache_v
@@ -329,10 +351,11 @@ def mla_attention(params, x, cfg, *, positions):
     return out, (ckv, k_rope[:, :, 0, :])
 
 
-def mla_decode(params, x, cfg, cache_ckv, cache_krope, cache_len):
+def mla_decode(params, x, cfg, cache_ckv, cache_krope, lens, layer=None):
     """Absorbed MLA decode: attend in the latent space (DeepSeek-V2 trick).
 
-    cache_ckv [B,T,rank]; cache_krope [B,T,rope_dim].
+    cache_ckv [(L,)B,T,rank]; cache_krope [(L,)B,T,rope_dim]; lens [B].  As
+    :func:`gqa_decode`: the new rows are written at ``lens`` of ``layer``.
     """
     spec = cfg.mla
     B = x.shape[0]
@@ -340,7 +363,7 @@ def mla_decode(params, x, cfg, cache_ckv, cache_krope, cache_len):
     cq = rmsnorm({"scale": params["q_norm"]}, x @ params["wq_a"], cfg.norm_eps)
     q = (cq @ params["wq_b"]).reshape(B, 1, H, spec.qk_head_dim)
     q_nope, q_rope = jnp.split(q, [spec.qk_nope_head_dim], axis=-1)
-    pos = _decode_positions(cache_len)
+    pos = lens[:, None]
     q_rope = apply_rope_bshd(q_rope, pos, cfg.rope_theta)
 
     ckv_full = x @ params["wkv_a"]
@@ -348,8 +371,10 @@ def mla_decode(params, x, cfg, cache_ckv, cache_krope, cache_len):
     ckv_new = rmsnorm({"scale": params["kv_norm"]}, ckv_new, cfg.norm_eps)
     krope_new = apply_rope_bshd(krope_new[:, :, None, :], pos,
                                 cfg.rope_theta)[:, :, 0, :]
-    cache_ckv = _cache_write(cache_ckv, ckv_new, cache_len)
-    cache_krope = _cache_write(cache_krope, krope_new, cache_len)
+    cache_ckv = cache_write(cache_ckv, ckv_new, lens, layer)
+    cache_krope = cache_write(cache_krope, krope_new, lens, layer)
+    ckv = cache_layer(cache_ckv, layer)
+    krope = cache_layer(cache_krope, layer)
 
     # Absorb W_uk into q: wkv_b [rank, H*(nope+v)]
     wkv_b = params["wkv_b"].reshape(
@@ -359,19 +384,15 @@ def mla_decode(params, x, cfg, cache_ckv, cache_krope, cache_len):
     q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, w_uk)
 
     scale = spec.qk_head_dim ** -0.5
-    scores = (jnp.einsum("bqhr,btr->bhqt", q_lat, cache_ckv,
+    scores = (jnp.einsum("bqhr,btr->bhqt", q_lat, ckv,
                          preferred_element_type=F32)
-              + jnp.einsum("bqhe,bte->bhqt", q_rope, cache_krope,
+              + jnp.einsum("bqhe,bte->bhqt", q_rope, krope,
                            preferred_element_type=F32)) * scale
-    kv_pos = jnp.arange(cache_ckv.shape[1])
-    cl = jnp.asarray(cache_len)
-    if cl.ndim == 0:
-        valid = (kv_pos <= cl)[None, None, None, :]
-    else:
-        valid = (kv_pos[None, :] <= cl[:, None])[:, None, None, :]
+    kv_pos = jnp.arange(ckv.shape[1])
+    valid = (kv_pos[None, :] <= lens[:, None])[:, None, None, :]
     scores = jnp.where(valid, scores, NEG_INF)
-    weights = jax.nn.softmax(scores, axis=-1).astype(cache_ckv.dtype)
-    out_lat = jnp.einsum("bhqt,btr->bqhr", weights, cache_ckv)
+    weights = jax.nn.softmax(scores, axis=-1).astype(ckv.dtype)
+    out_lat = jnp.einsum("bhqt,btr->bqhr", weights, ckv)
     out = jnp.einsum("bqhr,rhv->bqhv", out_lat, w_uv)
     out = out.reshape(B, 1, -1) @ params["wo"]
     return out, cache_ckv, cache_krope

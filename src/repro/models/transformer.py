@@ -209,29 +209,37 @@ def apply_block_full(bp, x, cfg, mixer_kind, mlp_kind, positions,
     return x, cache, aux
 
 
-def apply_block_decode(bp, x, cfg, mixer_kind, mlp_kind, cache, cache_len):
+def apply_block_decode(bp, x, cfg, mixer_kind, mlp_kind, cache, lens,
+                       layer=None):
+    """One block's decode.  ``cache`` holds this block kind's leaves for
+    every layer of the stack [L,...] with ``layer`` the index, or for one
+    layer without.  K/V leaves get one new row per slot at ``lens`` [B];
+    SSM states are replaced whole at ``layer``; cross-attention K/V are
+    read only."""
     h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
     new_cache = dict(cache)
     if mixer_kind == "ssm":
         y, conv_state, ssd_state = ssm_mod.mamba2_decode(
-            bp["mixer"], h, cfg, cache["conv"], cache["ssd"])
-        new_cache["conv"], new_cache["ssd"] = (
-            conv_state.astype(cache["conv"].dtype), ssd_state.astype(F32))
+            bp["mixer"], h, cfg, attn_mod.cache_layer(cache["conv"], layer),
+            attn_mod.cache_layer(cache["ssd"], layer))
+        for name, state in (("conv", conv_state), ("ssd", ssd_state)):
+            state = state.astype(cache[name].dtype)
+            new_cache[name] = state if layer is None else \
+                jax.lax.dynamic_update_index_in_dim(cache[name], state,
+                                                    layer, 0)
     elif cfg.mla is not None:
-        y, ckv, krope = attn_mod.mla_decode(
-            bp["mixer"], h, cfg, cache["ckv"], cache["krope"], cache_len)
-        new_cache["ckv"], new_cache["krope"] = ckv, krope
+        y, new_cache["ckv"], new_cache["krope"] = attn_mod.mla_decode(
+            bp["mixer"], h, cfg, cache["ckv"], cache["krope"], lens, layer)
     else:
-        local = mixer_kind == "attn_local"
-        y, ck, cv = attn_mod.gqa_decode(
-            bp["mixer"], h, cfg, cache["k"], cache["v"], cache_len,
-            local=local)
-        new_cache["k"], new_cache["v"] = ck, cv
+        y, new_cache["k"], new_cache["v"] = attn_mod.gqa_decode(
+            bp["mixer"], h, cfg, cache["k"], cache["v"], lens,
+            local=mixer_kind == "attn_local", layer=layer)
     x = x + y
     if cfg.is_encdec:
         hx = rmsnorm(bp["ln_x"], x, cfg.norm_eps)
-        y = attn_mod.cross_attention(bp["xattn"], hx, cache["xk"],
-                                     cache["xv"], cfg)
+        y = attn_mod.cross_attention(
+            bp["xattn"], hx, attn_mod.cache_layer(cache["xk"], layer),
+            attn_mod.cache_layer(cache["xv"], layer), cfg)
         x = x + y
     x, _ = _apply_mlp(bp, x, cfg, mlp_kind)
     return x, new_cache
@@ -449,35 +457,41 @@ def prefill(params, cfg, tokens, frontend_embeds=None):
 
 
 def decode_step(params, cfg, token, caches, cache_len):
-    """One decode step.  token [B,1] int32; cache_len: current length.
+    """One decode step.  token [B,1] int32; cache_len: each row's current
+    length, [B] or a scalar for all rows.
 
+    The block caches ride in the layer loop's carry and each layer writes
+    only its new row per slot, so a donated cache is updated in place.
     Returns (logits [B,V] fp32, new caches).
     """
     x = embed(params["embed"], token, cfg.embed_scale)
+    lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32),
+                            (token.shape[0],))
 
-    def period_fn(x, xs):
-        pparams, pcache = xs
-        new_caches = {}
+    def period_fn(carry, xs):
+        x, blocks = carry
+        layer, pparams = xs
+        blocks = dict(blocks)
         for p_idx in range(cfg.period):
-            x, nc = apply_block_decode(
-                pparams[str(p_idx)], x, cfg, cfg.layer_pattern[p_idx],
-                cfg.mlp_pattern[p_idx], pcache[str(p_idx)], cache_len)
-            new_caches[str(p_idx)] = nc
-        return x, new_caches
+            key = str(p_idx)
+            x, blocks[key] = apply_block_decode(
+                pparams[key], x, cfg, cfg.layer_pattern[p_idx],
+                cfg.mlp_pattern[p_idx], blocks[key], lens, layer)
+        return (x, blocks), None
 
-    x, new_block_caches = _scan(
-        period_fn, x, (params["blocks"], caches["blocks"]))
+    (x, new_blocks), _ = _scan(
+        period_fn, (x, caches["blocks"]),
+        (jnp.arange(cfg.n_periods), params["blocks"]))
 
     new_rem = {}
     for i in range(cfg.n_remainder):
-        x, nc = apply_block_decode(
+        x, new_rem[str(i)] = apply_block_decode(
             params["rem"][str(i)], x, cfg, cfg.layer_pattern[i],
-            cfg.mlp_pattern[i], caches["rem"][str(i)], cache_len)
-        new_rem[str(i)] = nc
+            cfg.mlp_pattern[i], caches["rem"][str(i)], lens)
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = logits_last(params, cfg, x)
-    return logits, {"blocks": new_block_caches, "rem": new_rem}
+    return logits, {"blocks": new_blocks, "rem": new_rem}
 
 
 # ==========================================================================

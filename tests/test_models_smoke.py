@@ -90,6 +90,83 @@ def test_smoke_prefill_decode_consistency(arch):
     assert jax.tree.structure(new_caches) == jax.tree.structure(caches)
 
 
+ROWS = ("k", "v", "ckv", "krope")     # cache leaves with a T axis
+
+
+def _leaf_name(path):
+    keys = [str(getattr(k, "key", k)) for k in path]
+    return keys[-1], "blocks" in keys
+
+
+@pytest.mark.parametrize("arch", ASSIGNED)
+def test_ragged_decode_writes_one_row_per_slot(arch):
+    """One decode step with a different length in each slot: each row's
+    logits match its own prefill, each K/V leaf changes only at
+    (layer, b, lens[b]), SSM states become the prefill's states, and the
+    cross-attention caches stay as they were."""
+    cfg = reduced_config(arch)
+    B, S = 3, 24
+    n = (5, 11, 17)                              # prompt tokens of each slot
+    tokens, _, fe = _inputs(cfg, B, S)
+    params = init_params(cfg, K1)
+    P = cfg.frontend_len if cfg.frontend == "patch_stub" else 0
+    lens = np.array(n) + P                       # cache positions filled
+
+    run_prefill = jax.jit(prefill, static_argnums=1)
+
+    def row(b, m):
+        return run_prefill(params, cfg, tokens[b:b + 1, :m],
+                           None if fe is None else fe[b:b + 1])
+
+    def fill(path, dst, *srcs):
+        b_ax = 1 if _leaf_name(path)[1] else 0
+        srcs = [jnp.pad(s, [(0, 0) if ax == b_ax else (0, d - a)
+                            for ax, (a, d) in enumerate(zip(s.shape,
+                                                            dst.shape))])
+                for s in srcs]
+        return jnp.concatenate(srcs, axis=b_ax).astype(dst.dtype)
+
+    caches = jax.tree_util.tree_map_with_path(
+        fill, init_cache(cfg, B, S + P), *[row(b, n[b])[1] for b in range(B)])
+    refs = [row(b, n[b] + 1) for b in range(B)]
+    logits, new = jax.jit(decode_step, static_argnums=1)(
+        params, cfg, tokens[np.arange(B), n][:, None], caches,
+        jnp.asarray(lens, jnp.int32))
+
+    for b in range(B):
+        err = float(jnp.max(jnp.abs(logits[b] - refs[b][0][0])))
+        assert err < 2e-4, f"{arch}: slot {b} decode/prefill mismatch {err}"
+    assert jax.tree.structure(new) == jax.tree.structure(caches)
+
+    def check(path, old, got, *ref):
+        name, stacked = _leaf_name(path)
+        old, got = np.asarray(old), np.asarray(got)
+        assert got.shape == old.shape and got.dtype == old.dtype
+        lead = (slice(None),) if stacked else ()
+        if name in ROWS:
+            written = np.zeros(old.shape, bool)
+            for b in range(B):
+                written[lead + (b, lens[b])] = True
+            assert got[~written].tobytes() == old[~written].tobytes(), (
+                f"{arch}: {name} changed outside the new rows")
+            for b in range(B):
+                np.testing.assert_allclose(
+                    got[lead + (b, lens[b])],
+                    np.asarray(ref[b])[lead + (0, lens[b])],
+                    rtol=1e-4, atol=2e-4)
+        elif name in ("conv", "ssd"):
+            for b in range(B):
+                np.testing.assert_allclose(
+                    got[lead + (b,)], np.asarray(ref[b])[lead + (0,)],
+                    rtol=1e-4, atol=2e-4)
+        else:
+            assert name in ("xk", "xv"), name
+            assert got.tobytes() == old.tobytes(), f"{arch}: {name} written"
+
+    jax.tree_util.tree_map_with_path(check, caches, new,
+                                     *[r[1] for r in refs])
+
+
 def test_param_counts_sane():
     # full configs: analytic counts in the right ballpark (catches config typos)
     expect = {

@@ -1,4 +1,4 @@
-"""Main-path kernels and the llsc-100m decode step compiled for a TPU v5e.
+"""Main-path kernels and the serving decode step compiled for a TPU v5e.
 
 Nothing runs: each program is compiled for a described (not attached) v5e
 chip, which finds what interpret mode cannot, such as block shapes Mosaic
@@ -6,6 +6,7 @@ refuses.  The kernels are called with ``interpret=False`` because
 ``kernels.ops`` picks interpret mode from the process's backend, which is the
 CPU here.
 """
+import dataclasses
 import os
 
 import jax
@@ -103,3 +104,38 @@ def test_llsc_100m_decode_step_compiles(one_chip):
     mem = compiled.memory_analysis()
     # bf16 weights (~0.22 GB) and KV cache (~0.6 GB) fit one 16 GiB chip
     assert 0.5e9 < mem.argument_size_in_bytes < 2e9
+
+
+def _phi3_medium_windowed_l2():
+    """GQA 4:1 with d_head 128, every layer windowed, two layers."""
+    return dataclasses.replace(
+        get_config("phi3-medium-14b"), n_layers=2,
+        layer_pattern=("attn_local",), mlp_pattern=("mlp",), attn_window=2047)
+
+
+@pytest.mark.parametrize("make_cfg,B,T", [
+    (lambda: get_config("llsc-100m"), 8, 2048),
+    (_phi3_medium_windowed_l2, 16, 4096),
+], ids=["llsc-100m-8x2048", "phi3-medium-windowed-l2-16x4096"])
+def test_decode_step_updates_cache_in_place(one_chip, make_cfg, B, T):
+    """With the cache donated, as the engine donates it, the decode step
+    writes its new rows into the cache's own buffer: the whole cache is
+    aliased to the output and the step's scratch stays under one layer's
+    K cache, so no copy of a layer or of the stack is made."""
+    cfg = make_cfg()
+    on_chip = lambda s: _spec(one_chip, s.shape, s.dtype)  # noqa: E731
+    params = jax.tree.map(on_chip, model_lib.init_params_shape(cfg))
+    caches = jax.tree.map(on_chip, model_lib.cache_struct(cfg, B, T))
+    compiled = jax.jit(
+        lambda p, t, c, l: model_lib.decode_step(p, cfg, t, c, l),
+        donate_argnums=(2,)).lower(
+            params, _spec(one_chip, (B, 1), jnp.int32), caches,
+            _spec(one_chip, (B,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(caches))
+    one_layer_k = B * T * cfg.n_kv_heads * cfg.d_head * 2
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < one_layer_k, (
+        f"decode temp {mem.temp_size_in_bytes} B >= one layer's K "
+        f"{one_layer_k} B: the step copies the cache")
