@@ -62,9 +62,10 @@ def build(bench, workload: str, seed: int, allow_cpu=False, peak=None):
         peak = lookup(dev.device_kind)
     conf = bench.config(w["config"])
     mix = bench.traffic(w["traffic"])
+    arch, ref = bench.architecture(conf)
     ctx = types.SimpleNamespace(
-        bench=bench, workload=w, conf=conf, mix=mix, seed=seed,
-        dims=spec_mod.dims_of(conf), cfg=spec_mod.model_config(conf),
+        bench=bench, workload=w, conf=conf, mix=mix, seed=seed, arch=arch,
+        ref=ref, dims=arch.dims(conf), cfg=arch.program_config(conf),
         limits=bench.limits(workload), peak=peak, log=log,
         key=jax.random.PRNGKey(seed),
         # the program's own seeds (its feed, its sampler) take 31 bits

@@ -23,8 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import flops, traffic
-from references import dense_gqa as ref
+from chipbench import traffic
 
 
 class Cell:
@@ -49,7 +48,8 @@ class Cell:
 
         ctx, mix = self.ctx, self.mix
         self.params = jax.jit(functools.partial(
-            ref.make_params, ctx.dims, dtype=jnp.dtype(ctx.dims.dtype)))(ctx.key)
+            ctx.ref.make_params, ctx.dims,
+            dtype=jnp.dtype(ctx.dims.dtype)))(ctx.key)
         self.engine = e = ServeEngine(ctx.cfg, self.params, EngineConfig(
             slots=mix["slots"], max_seq_len=mix["max_seq_len"], greedy=True,
             seed=ctx.program_seed, job_name=f"chipbench:{ctx.workload['name']}",
@@ -127,17 +127,17 @@ class Cell:
         done = {c.request_id: c for c in e.completions}
         failed = sum(1 for r in admitted if r.request_id in done
                      and not self._valid(r, done[r.request_id].tokens))
-        d = self.ctx.dims
-        work = sum(flops.prefill_flops(d, len(r.prompt)) for r in admitted)
+        d, arch = self.ctx.dims, self.ctx.arch
+        work = sum(arch.prefill_flops(d, len(r.prompt)) for r in admitted)
         decoded = 0
         for c in done.values():
-            work += flops.decode_flops(d, c.prompt_len, len(c.tokens) - 1)
+            work += arch.decode_flops(d, c.prompt_len, len(c.tokens) - 1)
             decoded += len(c.tokens) - 1
         # requests still in their slots: their decoded tokens, shared evenly
         live = [r for r in admitted if r.request_id not in done]
         rest = stats["tokens"] - len(admitted) - decoded
         for r in live:
-            work += flops.decode_flops(d, len(r.prompt), rest // len(live))
+            work += arch.decode_flops(d, len(r.prompt), rest // len(live))
         self.done = list(done.values())
         self.prompts = {r.request_id: r.prompt for r in admitted}
         return {"window_s": wall, "steps": stats["steps"],
@@ -199,13 +199,13 @@ class Cell:
         return float(np.max(np.concatenate(gaps)))
 
     def readings(self) -> dict:
-        dims = self.ctx.dims
+        dims, ref = self.ctx.dims, self.ctx.ref
         fn = jax.jit(lambda p, t, g: ref.served_gaps(p, t, g, dims))
         return {"served_logit_gap": self._gaps(fn)}
 
     def control_readings(self) -> dict:
         """The same number with the reference computed in fp8 put in the
         program's place: the gap of the token it puts first."""
-        dims = self.ctx.dims
+        dims, ref = self.ctx.dims, self.ctx.ref
         fn = jax.jit(lambda p, t, g: ref.control_gaps(p, t, g, dims, ref.fp8))
         return {"control_fp8": {"served_logit_gap": self._gaps(fn)}}

@@ -1,45 +1,22 @@
 """Finding a cell's parts by name: its entry in ``BENCHMARK.json``, its
-configuration (``configs/<name>.json``), its traffic mix
-(``traffic/<name>.json``), its limits (``limits/<workload>.json``) and the
-reader of each metric (``metrics/<name>.py``).
+configuration (``configs/<name>.json``), the architecture that file names
+(``archs/<a>.py`` and ``references/<a>.py``; see ``archs/__init__.py``), its
+traffic mix (``traffic/<name>.json``), its limits
+(``limits/<workload>.json``) and the reader of each metric
+(``metrics/<name>.py``).
 
-A later cell, mix or metric is a new file and a new entry; nothing here
-names one.
+A later cell, mix, metric or architecture is a new file and a new entry;
+nothing here names one.
 """
 from __future__ import annotations
 
-import dataclasses
 import importlib.util
 import json
+import sys
 from pathlib import Path
 
 BENCH_DIR = Path(__file__).resolve().parents[1]      # benchmarks/chip
 CHECKOUT = BENCH_DIR.parents[1]                        # root of the checkout
-
-
-@dataclasses.dataclass(frozen=True)
-class Dims:
-    """The sizes of a dense GQA decoder, in the program's terms."""
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    d_head: int
-    d_ff: int
-    vocab_size: int
-    tie_embeddings: bool
-    norm_eps: float
-    rope_theta: float
-    dtype: str
-    window: int | None = None     # a query attends to keys q - k < window
-
-
-# configuration file key (as in a Hugging Face config.json) -> Dims field
-_KEYS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-         "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-         "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-         "tie_word_embeddings": "tie_embeddings", "rms_norm_eps": "norm_eps",
-         "rope_theta": "rope_theta", "torch_dtype": "dtype"}
 
 
 class Bench:
@@ -50,6 +27,7 @@ class Bench:
         self.root = Path(root) if root else BENCH_DIR
         self.spec = spec if spec is not None else json.loads(
             (CHECKOUT / "BENCHMARK.json").read_text())
+        self._modules = {}
 
     def _json(self, sub: str, name: str) -> dict:
         return json.loads((self.root / sub / f"{name}.json").read_text())
@@ -85,40 +63,34 @@ class Bench:
             return m["moves"] in moved
         return [m for m in self.spec["per_layer"] if wanted(m)]
 
+    def _module(self, sub: str, name: str):
+        """``<sub>/<name>.py`` under the root, executed once for this
+        bench; it stands in ``sys.modules`` under a name of its own, as a
+        dataclass defined in it needs."""
+        if (sub, name) not in self._modules:
+            path = self.root / sub / f"{name}.py"
+            mod_name = f"chipbench_{sub}_" + name.replace(".", "_")
+            mod_spec = importlib.util.spec_from_file_location(mod_name, path)
+            mod = importlib.util.module_from_spec(mod_spec)
+            sys.modules[mod_name] = mod
+            mod_spec.loader.exec_module(mod)
+            self._modules[sub, name] = mod
+        return self._modules[sub, name]
+
     def reader(self, metric: str):
-        path = self.root / "metrics" / f"{metric}.py"
-        mod_spec = importlib.util.spec_from_file_location(
-            "chipbench_metric_" + metric.replace(".", "_"), path)
-        mod = importlib.util.module_from_spec(mod_spec)
-        mod_spec.loader.exec_module(mod)
-        return mod.read
+        return self._module("metrics", metric).read
 
-
-def dims_of(conf: dict) -> Dims:
-    vals = {field: conf[key] for key, field in _KEYS.items()}
-    vals["d_head"] = conf.get("head_dim",
-                              conf["hidden_size"] // conf["num_attention_heads"])
-    vals["window"] = conf.get("sliding_window")
-    return Dims(**vals)
-
-
-def model_config(conf: dict):
-    """The program's ``ModelConfig`` for a configuration file: the program's
-    registered architecture named by ``program_base``, with every size the
-    file states put in.  A ``sliding_window`` makes every layer a windowed
-    one (the program's ``attn_local``), as the config states it."""
-    from repro.configs import get_config
-
-    if conf.get("hidden_act") != "silu":
-        raise SystemExit("chipbench: only SwiGLU (hidden_act silu) blocks")
-    d = dims_of(conf)
-    kind = "attn_local" if d.window else "attn"
-    return dataclasses.replace(
-        get_config(conf["program_base"]), name=conf["name"],
-        n_layers=d.n_layers, d_model=d.d_model, n_heads=d.n_heads,
-        n_kv_heads=d.n_kv_heads, d_head=d.d_head, d_ff=d.d_ff,
-        vocab_size=d.vocab_size, tie_embeddings=d.tie_embeddings,
-        norm_eps=d.norm_eps, rope_theta=d.rope_theta, act="swiglu",
-        dtype=d.dtype, layer_pattern=(kind,), mlp_pattern=("mlp",),
-        qkv_bias=False, embed_scale=1.0, attn_window=d.window,
-        rope_theta_local=None, attn_logit_softcap=None)
+    def architecture(self, conf: dict) -> tuple:
+        """(the program-facing module, the plain reference) of the
+        architecture a configuration file names."""
+        name = conf.get("architecture")
+        if name is None:
+            name = self._module("archs", "__init__").DEFAULT
+        missing = [str(self.root / sub / f"{name}.py")
+                   for sub in ("archs", "references")
+                   if not (self.root / sub / f"{name}.py").is_file()]
+        if missing:
+            raise SystemExit(f"chipbench: architecture {name!r} of "
+                             f"configuration {conf.get('name')!r} has no "
+                             f"{' and no '.join(missing)}")
+        return self._module("archs", name), self._module("references", name)

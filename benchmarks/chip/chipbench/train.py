@@ -20,9 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import flops
-from references import dense_gqa as ref
-
 F32 = jnp.float32
 
 
@@ -49,9 +46,10 @@ def norm_gap(prog: np.ndarray, refn: np.ndarray, keep=None) -> float:
     return float(np.max(gaps[keep]))
 
 
-def reference_steps(params0, batches, dims, opt, quant=ref.identity, rows=4,
+def reference_steps(ref, params0, batches, dims, opt, quant=None, rows=4,
                     keep_rows=None):
-    """Three AdamW steps of the reference on the trainer's batches.
+    """Three AdamW steps of the reference module ``ref`` on the trainer's
+    batches, its operands rounded by ``quant`` (none by default).
 
     ``params0()`` makes the initial weights; they are made again at the
     end rather than held, and the gradient and the update run in place, so
@@ -59,6 +57,8 @@ def reference_steps(params0, batches, dims, opt, quant=ref.identity, rows=4,
     (losses, per-leaf norms of the first clipped gradient, per-leaf norms of
     the change after the steps).  ``keep_rows`` takes the first so many rows
     of each batch only (a fault: half the batch left out)."""
+    quant = quant or ref.identity
+
     def accumulate(grads, params, tok, lab):
         s, g = jax.value_and_grad(
             lambda p: ref.loss_sum(p, tok, lab, dims, quant))(params)
@@ -103,7 +103,7 @@ class Cell:
     def __init__(self, ctx):
         self.ctx = ctx
         self.B, self.S = ctx.mix["batch"], ctx.mix["seq_len"]
-        self._make = jax.jit(functools.partial(ref.make_params, ctx.dims,
+        self._make = jax.jit(functools.partial(ctx.ref.make_params, ctx.dims,
                                                dtype=F32))
 
     # -- the program's state, made from the seed by the benchmark ---------
@@ -133,7 +133,7 @@ class Cell:
         from repro.train.train_step import TrainState
 
         def init(key):
-            p = ref.make_params(ctx.dims, key, F32)
+            p = ctx.ref.make_params(ctx.dims, key, F32)
             return TrainState(p, init_opt_state(p, t.opt_cfg))
 
         self._init = jax.jit(init)
@@ -180,8 +180,8 @@ class Cell:
         return {"window_s": wall, "steps": self.steps, "tokens": tokens,
                 "attempted": self.steps,
                 "failed": int(sum(not np.isfinite(x) for x in losses)),
-                "flops": tokens * flops.train_flops_per_token(self.ctx.dims,
-                                                              self.S)}
+                "flops": tokens * self.ctx.arch.train_flops_per_token(
+                    self.ctx.dims, self.S)}
 
     def release(self):
         self.trainer._init_state = None
@@ -192,7 +192,7 @@ class Cell:
         ctx = self.ctx
         batches = [self.trainer.data.batch(s) for s in range(3)]
         with jax.default_matmul_precision("highest"):
-            return reference_steps(self._params0, batches, ctx.dims,
+            return reference_steps(ctx.ref, self._params0, batches, ctx.dims,
                                    ctx.conf["optimizer"],
                                    rows=ctx.mix["reference_rows"], **kw)
 
@@ -206,7 +206,8 @@ class Cell:
         a planted fault (half of each batch left out, the mean taken over
         the rest), each put in the program's place.  A step that returns
         its state unchanged reads 1 on ``update_norm_gap`` by definition."""
-        return {"control_fp8": self._compare(self._reference(quant=ref.fp8)),
+        return {"control_fp8": self._compare(
+                    self._reference(quant=self.ctx.ref.fp8)),
                 "fault_half_batch": self._compare(
                     self._reference(keep_rows=self.B // 2))}
 

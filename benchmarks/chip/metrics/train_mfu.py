@@ -1,6 +1,7 @@
-"""train_mfu: the window's training operations (``flops.train_flops_per_token``
-x tokens: forward and backward matmuls and causal attention, no
-recomputation) over its wall time, as a share of the chip's bf16 peak."""
+"""train_mfu: the window's training operations (the architecture's
+``train_flops_per_token`` x tokens, ``archs/<a>.py``: forward and backward
+matmuls and causal attention, no recomputation) over its wall time, as a
+share of the chip's bf16 peak."""
 
 
 def read(run):

@@ -1,7 +1,9 @@
 """A benchmark of tiny cells for CPU tests, built in a temporary directory
 from files found by name, as a later cell's would be: a configuration
 (Phi-3-mini's file with small widths, windowed or not), a training and a
-serving mix, their limits, and the real metric readers."""
+serving mix, their limits, the real metric readers and architectures, and
+one architecture that only this directory has (``wrapped_gqa``: the dense
+GQA files under another name)."""
 import json
 import shutil
 import sys
@@ -32,6 +34,22 @@ TRAIN_LIMITS = {"loss_rel_gap": 6e-4, "grad_norm_gap": 7e-3,
                 "update_norm_gap": 1e-2}
 SERVE_LIMITS = {"served_logit_gap": 0.1}
 
+# archs/wrapped_gqa.py and references/wrapped_gqa.py: their sibling
+# dense_gqa.py under another name
+WRAPPED = '''"""dense_gqa under another name."""
+import importlib.util
+import sys
+from pathlib import Path
+
+_path = Path(__file__).with_name("dense_gqa.py")
+_name = f"wrapped_{_path.parent.name}_dense_gqa"
+_spec = importlib.util.spec_from_file_location(_name, _path)
+_mod = sys.modules[_name] = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_mod)
+globals().update({k: v for k, v in vars(_mod).items()
+                  if not k.startswith("__")})
+'''
+
 
 def _write(path: Path, obj):
     path.parent.mkdir(parents=True, exist_ok=True)
@@ -39,19 +57,31 @@ def _write(path: Path, obj):
 
 
 def bench(root: Path) -> spec.Bench:
-    shutil.copytree(BENCH / "metrics", root / "metrics")
-    for name, tied, window in (("tiny", True, 40), ("tiny-untied", False, None)):
+    for sub in ("metrics", "archs", "references"):
+        shutil.copytree(BENCH / sub, root / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+        if sub != "metrics":
+            (root / sub / "wrapped_gqa.py").write_text(WRAPPED)
+    for name, tied, window, arch in (
+            ("tiny", True, 40, None), ("tiny-untied", False, None, None),
+            ("tiny-wrapped", True, 40, "wrapped_gqa")):
         conf = json.loads((BENCH / "configs/phi3-mini-4k-l4.json").read_text())
         conf.update(name=name, num_hidden_layers=2, hidden_size=64,
                     num_attention_heads=4, num_key_value_heads=2,
                     intermediate_size=128, vocab_size=512,
                     tie_word_embeddings=tied, sliding_window=window)
+        if arch:
+            conf["architecture"] = arch
         _write(root / "configs" / f"{name}.json", conf)
     _write(root / "traffic/tiny-train.json", TRAIN)
     _write(root / "traffic/tiny-serve.json", SERVE)
     workloads = [("tiny.train", "tiny", "tiny-train", TRAIN_LIMITS),
                  ("tiny.serve", "tiny", "tiny-serve", SERVE_LIMITS),
                  ("tiny-untied.serve", "tiny-untied", "tiny-serve",
+                  SERVE_LIMITS),
+                 ("tiny-wrapped.train", "tiny-wrapped", "tiny-train",
+                  TRAIN_LIMITS),
+                 ("tiny-wrapped.serve", "tiny-wrapped", "tiny-serve",
                   SERVE_LIMITS)]
     b = json.loads((CHECKOUT / "BENCHMARK.json").read_text())
     b["workloads"] = []

@@ -1,6 +1,7 @@
 """Each kind of cell run end to end on the CPU at a tiny size, through the
 harness's own ``main``, with the device refusal bypassed only here; the
-refusal itself; and a cell, a mix and a metric found by name."""
+refusal itself; and a cell, a mix, a metric and an architecture found by
+name."""
 import json
 import subprocess
 import sys
@@ -11,7 +12,8 @@ import chipbench_tiny as tiny
 
 @pytest.mark.parametrize("workload,trace", [
     ("tiny.train", 0), ("tiny.train", 1), ("tiny.serve", 0),
-    ("tiny.serve", 1), ("tiny-untied.serve", 0)])
+    ("tiny.serve", 1), ("tiny-untied.serve", 0), ("tiny-wrapped.train", 0),
+    ("tiny-wrapped.serve", 0)])
 def test_cell_runs_and_reports(tmp_path, capsys, workload, trace):
     res = tiny.run(tmp_path, workload, seed=2 ** 31 + 12345, trace=trace,
                    capsys=capsys)
@@ -76,6 +78,32 @@ def test_parts_found_by_name(tmp_path):
     assert b.reader("extra_metric")(run) == 42.0
     assert [m["name"] for m in b.metrics_for(w, trace=False)] == [
         "serve_tokens_per_s", "setup_s"]
+
+
+@pytest.mark.parametrize("workload", ["tiny-wrapped.train",
+                                      "tiny-wrapped.serve"])
+def test_architecture_found_by_name(tmp_path, workload):
+    """An architecture that a configuration file names, and that only the
+    bench root has, gives the cell its dims, program config, counts and
+    reference."""
+    ctx, cell = tiny.harness.build(tiny.bench(tmp_path), workload, 7,
+                                   allow_cpu=True, peak=tiny.PEAK)
+    assert ctx.arch.__file__ == str(tmp_path / "archs/wrapped_gqa.py")
+    assert ctx.ref.__file__ == str(tmp_path / "references/wrapped_gqa.py")
+    assert (ctx.dims.d_model, ctx.cfg.d_model) == (64, 64)
+    assert ctx.arch.prefill_flops(ctx.dims, 8) > 0
+    assert cell.kind == workload.rsplit(".", 1)[1]
+
+
+def test_missing_architecture_names_both_paths(tmp_path):
+    b = tiny.bench(tmp_path)
+    conf = b.config("tiny")
+    conf["architecture"] = "no_such_arch"
+    (tmp_path / "configs/tiny.json").write_text(json.dumps(conf))
+    with pytest.raises(SystemExit) as e:
+        tiny.harness.build(b, "tiny.serve", 7, allow_cpu=True, peak=tiny.PEAK)
+    for sub in ("archs", "references"):
+        assert str(tmp_path / sub / "no_such_arch.py") in str(e.value)
 
 
 def test_benchmark_json_names_only_files_that_exist():

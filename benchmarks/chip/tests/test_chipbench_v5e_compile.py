@@ -1,6 +1,7 @@
 """The benchmark's largest programs, compiled for a described TPU v5e (no
 chip): the Phi-3-medium one-stage prefill at 4096 tokens and its decode step
-at 16 slots x 4096, and the Phi-3-mini one-stage train step at 8 x 1024.
+at 16 slots x 4096, the Phi-3-mini one-stage decode step at 32 slots x 4096
+(the decode cell) and at 64 slots x 2048, and its train step at 8 x 1024.
 Each must compile and fit one chip's 16 GiB; ``memory_analysis`` gives the
 bytes.
 
@@ -8,6 +9,7 @@ The topology is described inside a module fixture, never at import, so that
 every test worker collects the same tests and only the one given this file
 loads the TPU compiler.
 """
+import functools
 import json
 import sys
 from pathlib import Path
@@ -41,9 +43,15 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@functools.cache
+def _bench():
+    return spec.Bench()
+
+
 def _cfg(name):
     conf = json.loads((HERE / "configs" / f"{name}.json").read_text())
-    return spec.model_config(conf)
+    arch, _ = _bench().architecture(conf)
+    return arch.program_config(conf)
 
 
 def _on(tree, sharding):
@@ -72,18 +80,35 @@ def test_phi3_l10_prefill_4096(one_chip):
     _bytes(compiled)
 
 
-def test_phi3_l10_decode_16x4096(one_chip):
+def _decode(name, slots, T, sharding):
+    """The decode step of configuration ``name`` at ``slots`` x ``T``, the
+    cache donated."""
     from repro.models import model as model_lib
 
-    cfg = _cfg("phi3-medium-4k-l10")
-    params = _on(model_lib.init_params_shape(cfg), one_chip)
-    caches = _on(model_lib.cache_struct(cfg, 16, 4096), one_chip)
-    tok = jax.ShapeDtypeStruct((16, 1), jnp.int32, sharding=one_chip)
-    lens = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    cfg = _cfg(name)
+    params = _on(model_lib.init_params_shape(cfg), sharding)
+    caches = _on(model_lib.cache_struct(cfg, slots, T), sharding)
+    tok = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=sharding)
+    lens = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=sharding)
     compiled = jax.jit(
         lambda p, t, c, n: model_lib.decode_step(p, cfg, t, c, n),
         donate_argnums=(2,)).lower(params, tok, caches, lens).compile()
     _bytes(compiled)
+
+
+def test_phi3_l10_decode_16x4096(one_chip):
+    _decode("phi3-medium-4k-l10", 16, 4096, one_chip)
+
+
+def test_phi3_mini_l4_decode_32x4096(one_chip):
+    """The decode cell's step."""
+    _decode("phi3-mini-4k-l4", 32, 4096, one_chip)
+
+
+def test_phi3_mini_l4_decode_64x2048(one_chip):
+    """The same cache bytes over twice the slots: twice the per-slot row
+    writes of each step."""
+    _decode("phi3-mini-4k-l4", 64, 2048, one_chip)
 
 
 def test_phi3_mini_l4_train_step_8x1024(one_chip):
