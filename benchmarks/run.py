@@ -727,7 +727,7 @@ def bench_steps():
     dstep = jax.jit(lambda p, t, c, l: decode_step(p, cfg, t, c, l))
 
     def decode_once():
-        out, _ = dstep(params, token, caches, jnp.int32(10))
+        out, _, _ = dstep(params, token, caches, jnp.int32(10))
         jax.block_until_ready(out)
 
     us = _timeit(decode_once, repeat=5, warmup=2)

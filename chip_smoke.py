@@ -161,7 +161,7 @@ def _decode_matches_prefill(cfg, params, prompts, T: int):
         caches, full)
     decode = jax.jit(lambda p, t, c, l: model_lib.decode_step(p, cfg, t, c, l))
     lens = jnp.full((B,), S - 1, jnp.int32)
-    logits_dec, _ = decode(params, prompts[:, -1:], caches, lens)
+    logits_dec, _, _ = decode(params, prompts[:, -1:], caches, lens)
     full_np = np.asarray(logits_full)
     dec_np = np.asarray(logits_dec)
     check(np.all(np.isfinite(dec_np)), "decode: non-finite logits")

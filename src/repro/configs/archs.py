@@ -1,4 +1,5 @@
 """Import side-effects: registers every architecture config."""
+import repro.configs.deepseek_v2_lite  # noqa: F401
 import repro.configs.gemma3_1b       # noqa: F401
 import repro.configs.granite_moe_1b  # noqa: F401
 import repro.configs.internvl2_2b    # noqa: F401
@@ -23,4 +24,5 @@ ASSIGNED = (
     "whisper-base",
     "qwen3-moe-30b-a3b",
     "granite-moe-1b-a400m",
+    "deepseek-v2-lite",
 )

@@ -31,6 +31,23 @@ class MoESpec:
     # if False: softmax over all experts then select (switch-style).
     norm_topk_prob: bool = True
     router_dtype: str = "float32"
+    # Expert parallelism's share: this chip holds the first n_local_experts
+    # of the router's n_experts (None: all of them).
+    n_local_experts: Optional[int] = None
+    # Shared experts (DeepSeek): one SwiGLU MLP of this width beside the
+    # routed ones, applied to every token (0: none).
+    d_ff_shared: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.n_local_experts is None \
+            else self.n_local_experts
+
+    @property
+    def holds_share(self) -> bool:
+        """True where the capacity path cannot run this layer: it holds a
+        share of the experts, or has shared experts."""
+        return self.n_held != self.n_experts or self.d_ff_shared > 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,9 +70,10 @@ class SSMSpec:
 
 @dataclasses.dataclass(frozen=True)
 class MLASpec:
-    """Multi-head latent attention (DeepSeek-V2 / MiniCPM3)."""
+    """Multi-head latent attention (DeepSeek-V2 / MiniCPM3).  A
+    ``q_lora_rank`` of None projects queries directly (DeepSeek-V2-Lite)."""
 
-    q_lora_rank: int = 768
+    q_lora_rank: Optional[int] = 768
     kv_lora_rank: int = 256
     qk_nope_head_dim: int = 64
     qk_rope_head_dim: int = 32
@@ -64,6 +82,19 @@ class MLASpec:
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRNSpec:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V2's config states
+    it under ``rope_scaling``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,11 +134,16 @@ class ModelConfig:
     # of ``pattern[: n_layers % len(pattern)]``.
     layer_pattern: Tuple[str, ...] = ("attn",)
     mlp_pattern: Tuple[str, ...] = ("mlp",)
+    # Leading layers of ``layer_pattern[0]``'s mixer with a dense MLP of
+    # ``d_ff`` (DeepSeek's first_k_dense_replace), unrolled before the
+    # scanned stack; they count in ``n_layers``.
+    n_dense_layers: int = 0
 
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
     # gemma3: local (sliding-window) layers use a different rope base.
     rope_theta_local: Optional[float] = None
+    rope_scaling: Optional[YaRNSpec] = None
     embed_scale: float = 1.0
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -158,11 +194,11 @@ class ModelConfig:
 
     @property
     def n_periods(self) -> int:
-        return self.n_layers // self.period
+        return (self.n_layers - self.n_dense_layers) // self.period
 
     @property
     def n_remainder(self) -> int:
-        return self.n_layers % self.period
+        return (self.n_layers - self.n_dense_layers) % self.period
 
     @property
     def is_encdec(self) -> bool:
@@ -235,7 +271,8 @@ def reduced_config(name_or_cfg) -> ModelConfig:
     """
     cfg = name_or_cfg if isinstance(name_or_cfg, ModelConfig) else get_config(name_or_cfg)
     period = cfg.period
-    n_layers = period + min(cfg.n_remainder, 1)
+    n_dense = min(cfg.n_dense_layers, 1)
+    n_layers = n_dense + period + min(cfg.n_remainder, 1)
     d_model = 64
     n_heads = 4
     n_kv = min(cfg.n_kv_heads, 2) if cfg.n_kv_heads < cfg.n_heads else 4
@@ -245,13 +282,15 @@ def reduced_config(name_or_cfg) -> ModelConfig:
         # capacity drops — keeps smoke tests deterministic w.r.t. grouping.
         moe = dataclasses.replace(
             cfg.moe, n_experts=8, top_k=min(cfg.moe.top_k, 2), d_ff_expert=32,
-            capacity_factor=4.0)
+            capacity_factor=4.0, n_local_experts=None,
+            d_ff_shared=64 if cfg.moe.d_ff_shared else 0)
     ssm = None
     if cfg.ssm is not None:
         ssm = dataclasses.replace(cfg.ssm, d_state=16, head_dim=16, chunk=16)
     mla = None
     if cfg.mla is not None:
-        mla = MLASpec(q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=8,
+        mla = MLASpec(q_lora_rank=32 if cfg.mla.q_lora_rank else None,
+                      kv_lora_rank=16, qk_nope_head_dim=8,
                       qk_rope_head_dim=8, v_head_dim=8)
     enc = None
     if cfg.encoder is not None:
@@ -261,6 +300,7 @@ def reduced_config(name_or_cfg) -> ModelConfig:
         cfg,
         name=cfg.name + "-reduced",
         n_layers=n_layers,
+        n_dense_layers=n_dense,
         embed_scale=math.sqrt(d_model) if cfg.embed_scale != 1.0 else 1.0,
         d_model=d_model,
         n_heads=n_heads,

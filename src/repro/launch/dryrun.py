@@ -116,7 +116,8 @@ def _extract_cost(compiled) -> dict:
 
 def _reduced_depth(cfg, periods: int):
     return dataclasses.replace(
-        cfg, name=f"{cfg.name}", n_layers=cfg.period * periods + cfg.n_remainder)
+        cfg, name=f"{cfg.name}",
+        n_layers=cfg.n_dense_layers + cfg.period * periods + cfg.n_remainder)
 
 
 def probe_costs(cfg, shape, mesh) -> dict:

@@ -17,7 +17,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope_bshd, rmsnorm, truncated_normal
+from repro.models.layers import (apply_rope_bshd, rmsnorm, truncated_normal,
+                                 yarn_mscale)
 from repro.models.scan_util import scan as _scan
 
 F32 = jnp.float32
@@ -51,11 +52,15 @@ def init_mla(key, d_model, n_heads, spec, dtype=jnp.float32):
     ks = jax.random.split(key, 5)
     std = d_model ** -0.5
     qk = spec.qk_head_dim
-    p = {
-        "wq_a": truncated_normal(ks[0], (d_model, spec.q_lora_rank), std, dtype),
-        "q_norm": jnp.ones((spec.q_lora_rank,), dtype),
-        "wq_b": truncated_normal(
-            ks[1], (spec.q_lora_rank, n_heads * qk), spec.q_lora_rank ** -0.5, dtype),
+    if spec.q_lora_rank is None:
+        p = {"wq": truncated_normal(ks[0], (d_model, n_heads * qk), std, dtype)}
+    else:
+        p = {"wq_a": truncated_normal(ks[0], (d_model, spec.q_lora_rank), std,
+                                      dtype),
+             "q_norm": jnp.ones((spec.q_lora_rank,), dtype),
+             "wq_b": truncated_normal(ks[1], (spec.q_lora_rank, n_heads * qk),
+                                      spec.q_lora_rank ** -0.5, dtype)}
+    p.update({
         "wkv_a": truncated_normal(
             ks[2], (d_model, spec.kv_lora_rank + spec.qk_rope_head_dim), std, dtype),
         "kv_norm": jnp.ones((spec.kv_lora_rank,), dtype),
@@ -66,7 +71,7 @@ def init_mla(key, d_model, n_heads, spec, dtype=jnp.float32):
         "wo": truncated_normal(
             ks[4], (n_heads * spec.v_head_dim, d_model),
             (n_heads * spec.v_head_dim) ** -0.5, dtype),
-    }
+    })
     return p
 
 
@@ -105,18 +110,20 @@ def _attend_block(qc, k, v, q_pos, kv_pos, *, causal, window, kv_valid_len,
 
 def chunked_attention(q, k, v, *, causal=True, window=None, q_offset=0,
                       kv_valid_len=None, softcap=None, chunk=1024,
-                      banded=False):
+                      banded=False, scale=None):
     """q [B,Sq,H,D]; k,v [B,Skv,Hkv,D] -> [B,Sq,H,D].
 
     ``q_offset``: position of q[0] within the kv sequence (decode: cache_len).
     ``kv_valid_len``: positions >= this are masked (ragged decode caches).
     ``banded``: for windowed layers, slice KV to the band instead of masking.
+    ``scale``: of the scores, D^-0.5 unless given.
     """
     B, Sq, H, D = q.shape
     Hk = k.shape[2]
     G = H // Hk
     Dv = v.shape[-1]  # MLA: value head dim != qk head dim
-    scale = D ** -0.5
+    if scale is None:
+        scale = D ** -0.5
     qg = q.reshape(B, Sq, Hk, G, D)
     Skv = k.shape[1]
 
@@ -317,38 +324,65 @@ def cross_kv(params, enc_out, n_kv_heads, d_head):
 # --------------------------------------------------------------------------
 
 
-def _mla_qkv_full(params, x, cfg):
-    """Naive MLA path (train/prefill): materialize per-head K and V."""
+def mla_scale(cfg) -> float:
+    """The scores' scale: qk_head_dim^-0.5, times m^2 under YaRN, where
+    m = yarn_mscale(factor, mscale_all_dim)."""
+    scale = cfg.mla.qk_head_dim ** -0.5
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        scale *= yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_q(params, x, cfg):
+    """Queries [B,S,H,qk] split into (nope, rope): directly from x, or
+    through the compressed query where the spec has a q_lora_rank."""
     spec = cfg.mla
     B, S, _ = x.shape
-    H = cfg.n_heads
-    cq = rmsnorm({"scale": params["q_norm"]}, x @ params["wq_a"], cfg.norm_eps)
-    q = (cq @ params["wq_b"]).reshape(B, S, H, spec.qk_head_dim)
-    q_nope, q_rope = jnp.split(q, [spec.qk_nope_head_dim], axis=-1)
+    if spec.q_lora_rank is None:
+        q = x @ params["wq"]
+    else:
+        cq = rmsnorm({"scale": params["q_norm"]}, x @ params["wq_a"],
+                     cfg.norm_eps)
+        q = cq @ params["wq_b"]
+    q = q.reshape(B, S, cfg.n_heads, spec.qk_head_dim)
+    return jnp.split(q, [spec.qk_nope_head_dim], axis=-1)
 
-    ckv_full = x @ params["wkv_a"]
-    ckv, k_rope = jnp.split(ckv_full, [spec.kv_lora_rank], axis=-1)
+
+def _mla_latent(params, x, cfg, positions):
+    """The cache rows of x: the normed latent c_kv [B,S,rank] and the
+    rotated rope key [B,S,rope] that every head shares."""
+    spec = cfg.mla
+    ckv, k_rope = jnp.split(x @ params["wkv_a"], [spec.kv_lora_rank], axis=-1)
     ckv = rmsnorm({"scale": params["kv_norm"]}, ckv, cfg.norm_eps)
-    kv = (ckv @ params["wkv_b"]).reshape(
-        B, S, H, spec.qk_nope_head_dim + spec.v_head_dim)
-    k_nope, v = jnp.split(kv, [spec.qk_nope_head_dim], axis=-1)
-    return q_nope, q_rope, k_nope, k_rope[:, :, None, :], v, ckv
+    k_rope = apply_rope_bshd(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                             cfg.rope_scaling)[:, :, 0, :]
+    return ckv, k_rope
 
 
 def mla_attention(params, x, cfg, *, positions):
-    """MLA for train/prefill. Returns (out, (ckv, k_rope)) for the cache."""
+    """Naive MLA for train/prefill: per-head K and V materialized from the
+    latent.  Returns (out, (ckv, k_rope)) for the cache."""
     spec = cfg.mla
     B, S, _ = x.shape
-    q_nope, q_rope, k_nope, k_rope, v, ckv = _mla_qkv_full(params, x, cfg)
-    q_rope = apply_rope_bshd(q_rope, positions, cfg.rope_theta)
-    k_rope = apply_rope_bshd(k_rope, positions, cfg.rope_theta)  # [B,S,1,r]
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope, k_nope.shape[:-1] + (spec.qk_rope_head_dim,))],
-        axis=-1)
-    out = chunked_attention(q, k, v, causal=True, chunk=cfg.attn_chunk)
-    out = out.reshape(B, S, -1) @ params["wo"]
-    return out, (ckv, k_rope[:, :, 0, :])
+    H = cfg.n_heads
+    with jax.named_scope("llload.mla"):
+        q_nope, q_rope = _mla_q(params, x, cfg)
+        q_rope = apply_rope_bshd(q_rope, positions, cfg.rope_theta,
+                                 cfg.rope_scaling)
+        ckv, k_rope = _mla_latent(params, x, cfg, positions)
+        kv = (ckv @ params["wkv_b"]).reshape(
+            B, S, H, spec.qk_nope_head_dim + spec.v_head_dim)
+        k_nope, v = jnp.split(kv, [spec.qk_nope_head_dim], axis=-1)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                      k_nope.shape[:-1] + k_rope.shape[-1:])],
+            axis=-1)
+        out = chunked_attention(q, k, v, causal=True, chunk=cfg.attn_chunk,
+                                scale=mla_scale(cfg))
+        out = out.reshape(B, S, -1) @ params["wo"]
+    return out, (ckv, k_rope)
 
 
 def mla_decode(params, x, cfg, cache_ckv, cache_krope, lens, layer=None):
@@ -360,39 +394,32 @@ def mla_decode(params, x, cfg, cache_ckv, cache_krope, lens, layer=None):
     spec = cfg.mla
     B = x.shape[0]
     H = cfg.n_heads
-    cq = rmsnorm({"scale": params["q_norm"]}, x @ params["wq_a"], cfg.norm_eps)
-    q = (cq @ params["wq_b"]).reshape(B, 1, H, spec.qk_head_dim)
-    q_nope, q_rope = jnp.split(q, [spec.qk_nope_head_dim], axis=-1)
-    pos = lens[:, None]
-    q_rope = apply_rope_bshd(q_rope, pos, cfg.rope_theta)
+    with jax.named_scope("llload.mla"):
+        q_nope, q_rope = _mla_q(params, x, cfg)
+        pos = lens[:, None]
+        q_rope = apply_rope_bshd(q_rope, pos, cfg.rope_theta, cfg.rope_scaling)
+        ckv_new, krope_new = _mla_latent(params, x, cfg, pos)
+        cache_ckv = cache_write(cache_ckv, ckv_new, lens, layer)
+        cache_krope = cache_write(cache_krope, krope_new, lens, layer)
+        ckv = cache_layer(cache_ckv, layer)
+        krope = cache_layer(cache_krope, layer)
 
-    ckv_full = x @ params["wkv_a"]
-    ckv_new, krope_new = jnp.split(ckv_full, [spec.kv_lora_rank], axis=-1)
-    ckv_new = rmsnorm({"scale": params["kv_norm"]}, ckv_new, cfg.norm_eps)
-    krope_new = apply_rope_bshd(krope_new[:, :, None, :], pos,
-                                cfg.rope_theta)[:, :, 0, :]
-    cache_ckv = cache_write(cache_ckv, ckv_new, lens, layer)
-    cache_krope = cache_write(cache_krope, krope_new, lens, layer)
-    ckv = cache_layer(cache_ckv, layer)
-    krope = cache_layer(cache_krope, layer)
+        # Absorb W_uk into q: wkv_b [rank, H*(nope+v)]
+        wkv_b = params["wkv_b"].reshape(
+            spec.kv_lora_rank, H, spec.qk_nope_head_dim + spec.v_head_dim)
+        w_uk = wkv_b[:, :, : spec.qk_nope_head_dim]   # [rank, H, nope]
+        w_uv = wkv_b[:, :, spec.qk_nope_head_dim:]    # [rank, H, v]
+        q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, w_uk)
 
-    # Absorb W_uk into q: wkv_b [rank, H*(nope+v)]
-    wkv_b = params["wkv_b"].reshape(
-        spec.kv_lora_rank, H, spec.qk_nope_head_dim + spec.v_head_dim)
-    w_uk = wkv_b[:, :, : spec.qk_nope_head_dim]   # [rank, H, nope]
-    w_uv = wkv_b[:, :, spec.qk_nope_head_dim:]    # [rank, H, v]
-    q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, w_uk)
-
-    scale = spec.qk_head_dim ** -0.5
-    scores = (jnp.einsum("bqhr,btr->bhqt", q_lat, ckv,
-                         preferred_element_type=F32)
-              + jnp.einsum("bqhe,bte->bhqt", q_rope, krope,
-                           preferred_element_type=F32)) * scale
-    kv_pos = jnp.arange(ckv.shape[1])
-    valid = (kv_pos[None, :] <= lens[:, None])[:, None, None, :]
-    scores = jnp.where(valid, scores, NEG_INF)
-    weights = jax.nn.softmax(scores, axis=-1).astype(ckv.dtype)
-    out_lat = jnp.einsum("bhqt,btr->bqhr", weights, ckv)
-    out = jnp.einsum("bqhr,rhv->bqhv", out_lat, w_uv)
-    out = out.reshape(B, 1, -1) @ params["wo"]
+        scores = (jnp.einsum("bqhr,btr->bhqt", q_lat, ckv,
+                             preferred_element_type=F32)
+                  + jnp.einsum("bqhe,bte->bhqt", q_rope, krope,
+                               preferred_element_type=F32)) * mla_scale(cfg)
+        kv_pos = jnp.arange(ckv.shape[1])
+        valid = (kv_pos[None, :] <= lens[:, None])[:, None, None, :]
+        scores = jnp.where(valid, scores, NEG_INF)
+        weights = jax.nn.softmax(scores, axis=-1).astype(ckv.dtype)
+        out_lat = jnp.einsum("bhqt,btr->bqhr", weights, ckv)
+        out = jnp.einsum("bqhr,rhv->bqhv", out_lat, w_uv)
+        out = out.reshape(B, 1, -1) @ params["wo"]
     return out, cache_ckv, cache_krope

@@ -6,6 +6,8 @@ the input; statistics (norm variance, softmax) accumulate in float32.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -39,17 +41,51 @@ def rmsnorm(params, x, eps: float = 1e-5):
 # --------------------------------------------------------------------------
 
 
-def rope_sincos(positions, dim: int, theta: float):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude factor 0.1 * mscale * ln(factor) + 1 (1 where the
+    context is not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_freq(dim: int, theta: float, scaling=None) -> np.ndarray:
+    """The dim/2 rotation frequencies; with a YaRN ``scaling``, pair i's is
+    divided by ``factor`` in proportion to ramp(i), which rises from 0 to 1
+    between the pairs that turn ``beta_fast`` and ``beta_slow`` times over
+    ``original_max_position_embeddings`` positions."""
+    inv_freq = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+    if scaling is None:
+        return inv_freq
+
+    def pair(turns):     # the pair that turns ``turns`` times, fractional
+        return dim * math.log(scaling.original_max_position_embeddings
+                              / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(pair(scaling.beta_fast)), 0)
+    high = min(math.ceil(pair(scaling.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return (inv_freq * (1 - ramp) + inv_freq / scaling.factor * ramp
+            ).astype(np.float32)
+
+
+def rope_sincos(positions, dim: int, theta: float, scaling=None):
     """positions [...,] int -> (sin, cos) each [..., dim/2] float32."""
     assert dim % 2 == 0
-    inv_freq = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))
+    inv_freq = rope_inv_freq(dim, theta, scaling)
     angles = positions.astype(F32)[..., None] * inv_freq  # [..., dim/2]
-    return jnp.sin(angles), jnp.cos(angles)
+    sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            sin, cos = sin * m, cos * m
+    return sin, cos
 
 
-def apply_rope_bshd(x, positions, theta: float):
+def apply_rope_bshd(x, positions, theta: float, scaling=None):
     """Apply RoPE to x [B, S, H, D] at integer positions [S] or [B, S]."""
-    sin, cos = rope_sincos(positions, x.shape[-1], theta)  # [(B,)S, D/2]
+    sin, cos = rope_sincos(positions, x.shape[-1], theta,
+                           scaling)  # [(B,)S, D/2]
     dtype = x.dtype
     d2 = x.shape[-1] // 2
     x1, x2 = x[..., :d2].astype(F32), x[..., d2:].astype(F32)

@@ -45,7 +45,9 @@ def _moe_block_count(cfg) -> int:
 
 
 def count_params_analytic(cfg, active_only: bool = False) -> int:
-    """Total params; with active_only, MoE experts count only top_k/E."""
+    """Total params (leading dense layers and shared experts included);
+    with active_only, the held experts count only the top_k/E share of them
+    that a token is expected to use."""
     total = count_params(cfg)
     if not active_only or cfg.moe is None:
         return total
@@ -54,8 +56,8 @@ def count_params_analytic(cfg, active_only: bool = False) -> int:
     if cfg.act != "swiglu":
         per_block_expert = 2 * cfg.d_model * spec.d_ff_expert
     n_moe = _moe_block_count(cfg)
-    inactive = n_moe * (spec.n_experts - spec.top_k) * per_block_expert
-    return total - inactive
+    idle = spec.n_held - spec.top_k * spec.n_held / spec.n_experts
+    return total - round(n_moe * idle * per_block_expert)
 
 
 def model_flops(cfg, n_tokens: int, *, training: bool) -> float:

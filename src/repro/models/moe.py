@@ -12,6 +12,9 @@ Design notes (TPU adaptation):
   * Over-capacity tokens are dropped (standard capacity-factor semantics);
     with a large enough factor the output equals the dense reference
     (property-tested in tests/test_moe.py).
+
+Serving, and any layer that holds only a share of the experts or has shared
+experts, runs :func:`moe_share_ffn` instead: it drops no token.
 """
 from __future__ import annotations
 
@@ -20,22 +23,29 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import truncated_normal
+from repro.models.layers import init_mlp, mlp, truncated_normal
 from repro.models.sharding_hints import shard_hint
 
 F32 = jnp.float32
 
 
 def init_moe(key, d_model: int, spec, dtype=jnp.float32):
+    """The router over all ``n_experts``, the held experts' weights
+    [n_held, ...], and the shared experts' MLP where the spec has one."""
     ks = jax.random.split(key, 4)
-    E, f = spec.n_experts, spec.d_ff_expert
+    E, f = spec.n_held, spec.d_ff_expert
     std_in = d_model ** -0.5
-    return {
-        "router": truncated_normal(ks[0], (d_model, E), std_in, F32),
+    p = {
+        "router": truncated_normal(ks[0], (d_model, spec.n_experts), std_in,
+                                   F32),
         "w1": truncated_normal(ks[1], (E, d_model, f), std_in, dtype),
         "w3": truncated_normal(ks[2], (E, d_model, f), std_in, dtype),
         "w2": truncated_normal(ks[3], (E, f, d_model), f ** -0.5, dtype),
     }
+    if spec.d_ff_shared:
+        p["shared"] = init_mlp(jax.random.fold_in(key, 4), d_model,
+                               spec.d_ff_shared, "swiglu", dtype)
+    return p
 
 
 def _route(logits, spec):
@@ -149,6 +159,81 @@ def moe_ffn(params, x, spec, act: str = "swiglu", n_groups=None):
     y = jax.vmap(lambda ob, cmb: _combine_group(ob, cmb, T, C))(out_buf, combine)
     y = shard_hint(y, "moe_out")                   # [G, T, d]
     return y.reshape(B, S, d)
+
+
+# Up to this many tokens the no-drop layer is bound by reading the held
+# experts' weights (a v5e does ~240 flops a byte of HBM), so every held
+# expert runs on every token at no cost and each layer's slice of the
+# scanned weights stays fused into its dot.  Past it only the routed rows
+# are multiplied (``jax.lax.ragged_dot``).
+DENSE_TOKENS = 256
+
+
+def moe_share_ffn(params, x, spec, act: str = "swiglu"):
+    """The expert layer as one chip of an expert-parallel deployment holds
+    it, dropping no token.  x [B, S, d] -> (y [B, S, d], hits [B, S, n_held]).
+
+    Each token is routed over all ``spec.n_experts``; the experts held here
+    (the first ``n_held`` of the router's) give their weighted part, y = sum
+    over the token's top-k experts e that are held of weight_e * E_e(x), and
+    the shared experts, where the spec has them, are added once.  What the
+    absent experts would add is left out.  Up to ``DENSE_TOKENS`` tokens
+    every held expert has a row for every token, its weight 0 where the
+    token did not pick it; past it the (token, expert) pairs are sorted by
+    expert and each held expert multiplies its own rows.  Either way no
+    grouping can drop a token.  ``hits`` marks the held experts each token
+    was routed to."""
+    B, S, d = x.shape
+    E = spec.n_held
+    with jax.named_scope("llload.moe"):
+        xf = x.reshape(-1, d)
+        logits = xf.astype(F32) @ params["router"].astype(F32)
+        weights, idx = _route(logits, spec)                       # [T, k]
+        # an expert held elsewhere has no column: its row is all 0
+        picked = jax.nn.one_hot(idx, E, dtype=F32)                # [T,k,E]
+        if xf.shape[0] <= DENSE_TOKENS:
+            y = _held_dense(params, xf, jnp.einsum("tk,tke->et", weights,
+                                                   picked), act)
+        else:
+            y = _held_ragged(params, xf, weights, idx, E, act)
+        if "shared" in params:
+            y = y + mlp(params["shared"], xf, "swiglu").astype(F32)
+        hits = jnp.sum(picked, axis=1)
+    return y.astype(x.dtype).reshape(B, S, d), hits.reshape(B, S, -1)
+
+
+def _hidden(up, params, act):
+    """The held experts' hidden activations; ``up(w)`` multiplies the rows
+    by the stacked weights w."""
+    h = up(params["w1"])
+    return jax.nn.silu(h) * up(params["w3"]) if act == "swiglu" \
+        else jax.nn.gelu(h)
+
+
+def _held_dense(params, xf, gate, act):
+    """Every held expert on every token, weighted by gate [E, T]."""
+    h = _hidden(lambda w: jnp.einsum("td,edf->etf", xf, w), params, act)
+    h = h * gate[..., None].astype(h.dtype)
+    return jnp.einsum("etf,efd->td", h, params["w2"],
+                      preferred_element_type=F32)
+
+
+def _held_ragged(params, xf, weights, idx, E, act):
+    """The routed (token, held expert) rows only, sorted by expert."""
+    k = idx.shape[1]
+    e = idx.reshape(-1)                                           # [T*k]
+    held = e < E
+    # pairs whose expert is held elsewhere sort last, past every group
+    key = jnp.where(held, e, E)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(key, E, dtype=jnp.int32), axis=0)
+    rows = xf[order // k]
+    h = _hidden(lambda w: jax.lax.ragged_dot(rows, w, sizes), params, act)
+    out = jax.lax.ragged_dot(h, params["w2"], sizes,
+                             preferred_element_type=F32)
+    w = jnp.where(held, weights.reshape(-1), 0.0)[order]
+    out = jnp.where(held[order][:, None], out * w[:, None], 0.0)
+    return out[jnp.argsort(order)].reshape(-1, k, out.shape[-1]).sum(axis=1)
 
 
 def moe_ffn_dense_reference(params, x, spec, act: str = "swiglu"):

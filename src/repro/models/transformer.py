@@ -4,11 +4,13 @@ A model is ``n_periods`` copies of a *period* (``cfg.layer_pattern`` /
 ``cfg.mlp_pattern``) scanned with ``lax.scan`` (small HLO, fast compiles,
 native remat), plus an unrolled remainder of ``n_layers % period`` layers.
 Hybrid (jamba 1:7 attn:ssm), local:global (gemma3 5:1) and MoE-every-k
-patterns all reduce to this scheme.
+patterns all reduce to this scheme.  Leading dense layers
+(``cfg.n_dense_layers``, DeepSeek's first_k_dense_replace) are unrolled
+before the scanned stack, under ``params["dense"]``.
 
-Caches: a dict ``{"blocks": {str(pos): tree[n_periods, ...]},
-"rem": {str(i): tree}, "enc": ...}`` — scan-compatible because every leaf of
-``blocks`` carries the period axis in front.
+Caches: a dict ``{"dense": {str(i): tree}, "blocks": {str(pos):
+tree[n_periods, ...]}, "rem": {str(i): tree}}`` — scan-compatible because
+every leaf of ``blocks`` carries the period axis in front.
 """
 from __future__ import annotations
 
@@ -96,6 +98,12 @@ def init_params(cfg, key, dtype=None):
             lambda k: init_block(k, cfg, mixer_kind, mlp_kind, cross=cross,
                                  dtype=dtype))(pkeys)
     params["blocks"] = blocks
+    if cfg.n_dense_layers:
+        params["dense"] = {
+            str(i): init_block(jax.random.fold_in(keys[5], i), cfg,
+                               cfg.layer_pattern[0], "mlp", cross=cross,
+                               dtype=dtype)
+            for i in range(cfg.n_dense_layers)}
 
     rem = {}
     for i in range(cfg.n_remainder):
@@ -158,20 +166,27 @@ def _apply_cross_full(bp, x, cfg, enc_out, *, want_cache):
     return x + y, cache
 
 
-def _apply_mlp(bp, x, cfg, mlp_kind, *, want_aux=False):
-    """Returns (x, aux) where aux = [load_balance, z] router losses."""
+def _apply_mlp(bp, x, cfg, mlp_kind, *, want_aux=False, serving=False):
+    """Returns (x, aux, hits): aux = [load_balance, z] router losses; hits
+    [B,S,n_held] marks the held experts each token was routed to, where the
+    layer runs :func:`moe.moe_share_ffn` (None elsewhere).  Serving, and a
+    layer the capacity path cannot run, take that no-drop layer."""
     zero = jnp.zeros((2,), F32)
     if mlp_kind != "moe" and not bp["mlp"]:
-        return x, zero  # no FFN (mamba2)
+        return x, zero, None  # no FFN (mamba2)
     h = rmsnorm(bp["ln2"], x, cfg.norm_eps)
     if mlp_kind == "moe":
-        y = moe_mod.moe_ffn(bp["mlp"], h, cfg.moe, cfg.act)
+        hits = None
+        if serving or cfg.moe.holds_share:
+            y, hits = moe_mod.moe_share_ffn(bp["mlp"], h, cfg.moe, cfg.act)
+        else:
+            y = moe_mod.moe_ffn(bp["mlp"], h, cfg.moe, cfg.act)
         aux = zero
         if want_aux:
             lb, z = moe_mod.moe_aux_losses(bp["mlp"], h, cfg.moe)
             aux = jnp.stack([lb, z])
-        return x + y, aux
-    return x + mlp(bp["mlp"], h, cfg.act), zero
+        return x + y, aux, hits
+    return x + mlp(bp["mlp"], h, cfg.act), zero, None
 
 
 @jax.custom_vjp
@@ -201,7 +216,9 @@ def apply_block_full(bp, x, cfg, mixer_kind, mlp_kind, positions,
         x, xcache = _apply_cross_full(bp, x, cfg, enc_out,
                                       want_cache=want_cache)
         cache.update(xcache)
-    x, aux = _apply_mlp(bp, x, cfg, mlp_kind, want_aux=want_aux)
+    # a forward that keeps its cache is a prefill: serving
+    x, aux, _ = _apply_mlp(bp, x, cfg, mlp_kind, want_aux=want_aux,
+                           serving=want_cache)
     x = shard_hint(x, "activation")
     from repro.models.perf_flags import current as _perf
     if _perf().bf16_grads:
@@ -215,7 +232,8 @@ def apply_block_decode(bp, x, cfg, mixer_kind, mlp_kind, cache, lens,
     every layer of the stack [L,...] with ``layer`` the index, or for one
     layer without.  K/V leaves get one new row per slot at ``lens`` [B];
     SSM states are replaced whole at ``layer``; cross-attention K/V are
-    read only."""
+    read only.  Returns (x, cache, hits): hits [B,1,n_held] for an expert
+    layer, else None."""
     h = rmsnorm(bp["ln1"], x, cfg.norm_eps)
     new_cache = dict(cache)
     if mixer_kind == "ssm":
@@ -241,8 +259,8 @@ def apply_block_decode(bp, x, cfg, mixer_kind, mlp_kind, cache, lens,
             bp["xattn"], hx, attn_mod.cache_layer(cache["xk"], layer),
             attn_mod.cache_layer(cache["xv"], layer), cfg)
         x = x + y
-    x, _ = _apply_mlp(bp, x, cfg, mlp_kind)
-    return x, new_cache
+    x, _, hits = _apply_mlp(bp, x, cfg, mlp_kind, serving=True)
+    return x, new_cache, hits
 
 
 # ==========================================================================
@@ -312,6 +330,15 @@ def forward_hidden(params, cfg, tokens, frontend_embeds=None, *,
     S = x.shape[1]
     positions = jnp.arange(S)
 
+    aux0 = jnp.zeros((2,), F32)
+    dense_caches = {}
+    for i in range(cfg.n_dense_layers):
+        x, dense_caches[str(i)], a = apply_block_full(
+            params["dense"][str(i)], x, cfg, cfg.layer_pattern[0], "mlp",
+            positions, enc_out, want_cache=want_cache, banded=banded,
+            want_aux=want_aux)
+        aux0 = aux0 + a
+
     def period_fn(carry, pparams):
         x, aux = carry
         caches = {}
@@ -324,7 +351,6 @@ def forward_hidden(params, cfg, tokens, frontend_embeds=None, *,
             aux = aux + a
         return (x, aux), caches
 
-    aux0 = jnp.zeros((2,), F32)
     (x, aux), block_caches = _scan(_remat(period_fn, cfg), (x, aux0),
                                    params["blocks"])
 
@@ -340,7 +366,8 @@ def forward_hidden(params, cfg, tokens, frontend_embeds=None, *,
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     caches = None
     if want_cache:
-        caches = {"blocks": block_caches, "rem": rem_caches}
+        caches = {"dense": dense_caches, "blocks": block_caches,
+                  "rem": rem_caches}
     if want_aux:
         return x, caches, aux / max(cfg.n_layers, 1)
     return x, caches
@@ -462,36 +489,52 @@ def decode_step(params, cfg, token, caches, cache_len):
 
     The block caches ride in the layer loop's carry and each layer writes
     only its new row per slot, so a donated cache is updated in place.
-    Returns (logits [B,V] fp32, new caches).
+    Returns (logits [B,V] fp32, new caches, routed): for a model with
+    expert layers routed [B, n_held] counts each slot's token routed to
+    each held expert, summed over the layers; None for a model without.
     """
     x = embed(params["embed"], token, cfg.embed_scale)
-    lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32),
-                            (token.shape[0],))
+    B = token.shape[0]
+    lens = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (B,))
+
+    def tally(routed, hits):
+        return routed if hits is None else routed + hits.reshape(B, -1)
+
+    routed = (None if cfg.moe is None
+              else jnp.zeros((B, cfg.moe.n_held), F32))
+    new_dense = {}
+    for i in range(cfg.n_dense_layers):
+        x, new_dense[str(i)], _ = apply_block_decode(
+            params["dense"][str(i)], x, cfg, cfg.layer_pattern[0], "mlp",
+            caches["dense"][str(i)], lens)
 
     def period_fn(carry, xs):
-        x, blocks = carry
+        x, blocks, routed = carry
         layer, pparams = xs
         blocks = dict(blocks)
         for p_idx in range(cfg.period):
             key = str(p_idx)
-            x, blocks[key] = apply_block_decode(
+            x, blocks[key], hits = apply_block_decode(
                 pparams[key], x, cfg, cfg.layer_pattern[p_idx],
                 cfg.mlp_pattern[p_idx], blocks[key], lens, layer)
-        return (x, blocks), None
+            routed = tally(routed, hits)
+        return (x, blocks, routed), None
 
-    (x, new_blocks), _ = _scan(
-        period_fn, (x, caches["blocks"]),
+    (x, new_blocks, routed), _ = _scan(
+        period_fn, (x, caches["blocks"], routed),
         (jnp.arange(cfg.n_periods), params["blocks"]))
 
     new_rem = {}
     for i in range(cfg.n_remainder):
-        x, new_rem[str(i)] = apply_block_decode(
+        x, new_rem[str(i)], hits = apply_block_decode(
             params["rem"][str(i)], x, cfg, cfg.layer_pattern[i],
             cfg.mlp_pattern[i], caches["rem"][str(i)], lens)
+        routed = tally(routed, hits)
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = logits_last(params, cfg, x)
-    return logits, {"blocks": new_blocks, "rem": new_rem}
+    new = {"dense": new_dense, "blocks": new_blocks, "rem": new_rem}
+    return logits, new, routed
 
 
 # ==========================================================================
@@ -534,4 +577,6 @@ def init_cache(cfg, B: int, T: int):
             one)
     rem = {str(i): _block_cache_struct(cfg, cfg.layer_pattern[i], B, T)
            for i in range(cfg.n_remainder)}
-    return {"blocks": blocks, "rem": rem}
+    dense = {str(i): _block_cache_struct(cfg, cfg.layer_pattern[0], B, T)
+             for i in range(cfg.n_dense_layers)}
+    return {"dense": dense, "blocks": blocks, "rem": rem}

@@ -8,7 +8,9 @@ steps the number of concurrent streams 1 -> 2 -> 4 -> 8 exactly like LLSC
 steps tasks-per-GPU, saturating the device with co-resident low-duty work.
 Each step opens the ``llload.serve.*`` profiler spans
 (``repro.monitor.SPAN_NAMES``); the device programs are ``jit_serve_prefill``
-and ``jit_serve_decode``.
+and ``jit_serve_decode``.  For a model with expert layers the decode program
+also sums, on the device, the live tokens routed to each held expert; ``run``
+reads the sum once, when it returns.
 """
 from __future__ import annotations
 
@@ -73,8 +75,16 @@ class ServeEngine:
         self.peak_flops = (hw.resolve_peak_flops(ecfg.peak_flops)
                            if ecfg.monitor else None)
 
-        def serve_decode(params, tokens, caches, lens):
-            return model_lib.decode_step(params, cfg, tokens, caches, lens)
+        def serve_decode(params, tokens, caches, lens, live, routed):
+            """``live`` [B] (1.0 for a slot that holds a request) and
+            ``routed``, the running sum [n_held], are None for a model
+            without expert layers; else ``routed`` comes back with this
+            step's live tokens added."""
+            logits, caches, step = model_lib.decode_step(
+                params, cfg, tokens, caches, lens)
+            if step is not None:
+                routed = routed + jnp.einsum("b,be->e", live, step)
+            return logits, caches, routed
 
         def serve_prefill(params, tokens):
             return model_lib.prefill(params, cfg, tokens)
@@ -141,7 +151,10 @@ class ServeEngine:
     def run(self, *, max_steps: int = 10_000) -> dict:
         """Drain the queue.  Returns throughput stats, with this run's
         admissions and prompt tokens prefilled, and the mean duty cycle the
-        monitor hook published (None with monitoring off)."""
+        monitor hook published (None with monitoring off).  For a model
+        with expert layers, also the decode steps' live tokens routed to
+        each held expert, summed over the layers (``routed_per_expert``),
+        their mean and their max over the mean."""
         cfg, ecfg = self.cfg, self.ecfg
         B, T = ecfg.slots, ecfg.max_seq_len
         with TraceAnnotation("llload.serve.init"):
@@ -150,6 +163,8 @@ class ServeEngine:
         active: List[Optional[Request]] = [None] * B
         outputs: List[List[int]] = [[] for _ in range(B)]
         last = np.zeros(B, np.int32)
+        routed = (None if cfg.moe is None
+                  else jnp.zeros((cfg.moe.n_held,), jnp.float32))
 
         t_start = time.perf_counter()
         tokens_out = 0
@@ -186,9 +201,11 @@ class ServeEngine:
                 t0 = time.perf_counter()
                 with TraceAnnotation("llload.serve.decode"):
                     # each slot writes its new token at position lens[s]
-                    logits, caches = self._decode(
+                    live = (None if routed is None else jnp.asarray(
+                        [a is not None for a in active], jnp.float32))
+                    logits, caches, routed = self._decode(
                         self.params, jnp.asarray(last[:, None]), caches,
-                        jnp.asarray(lens))
+                        jnp.asarray(lens), live, routed)
                 with TraceAnnotation("llload.serve.sample"):
                     nxt = np.asarray(self._select(logits, steps), np.int32)
                 dt = time.perf_counter() - t0
@@ -226,6 +243,13 @@ class ServeEngine:
                     duties.append(util.duty_cycle)
 
         wall = time.perf_counter() - t_start
+        moe = {}
+        if routed is not None:
+            per = np.asarray(routed, np.float64)
+            mean = float(per.mean())
+            moe = {"routed_per_expert": per.tolist(), "routed_mean": mean,
+                   "routed_max_over_mean": (float(per.max()) / mean
+                                            if mean > 0 else None)}
         return {
             "requests": len(self.completions),
             "tokens": tokens_out,
@@ -236,6 +260,7 @@ class ServeEngine:
             "tokens_per_s": tokens_out / wall if wall > 0 else 0.0,
             "duty_mean": float(np.mean(duties)) if duties else None,
             "decision": self.controller.decide(ecfg.slots),
+            **moe,
         }
 
 

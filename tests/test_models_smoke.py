@@ -82,7 +82,7 @@ def test_smoke_prefill_decode_consistency(arch):
 
     caches = jax.tree.map(grow, caches, cap)
     pos = S - 1 + (cfg.frontend_len if cfg.frontend == "patch_stub" else 0)
-    logits_dec, new_caches = decode_step(params, cfg, tokens[:, S - 1:],
+    logits_dec, new_caches, _ = decode_step(params, cfg, tokens[:, S - 1:],
                                          caches, jnp.int32(pos))
     err = float(jnp.max(jnp.abs(logits_dec - logits_full)))
     assert err < 2e-4, f"{arch}: decode/prefill mismatch {err}"
@@ -129,7 +129,7 @@ def test_ragged_decode_writes_one_row_per_slot(arch):
     caches = jax.tree_util.tree_map_with_path(
         fill, init_cache(cfg, B, S + P), *[row(b, n[b])[1] for b in range(B)])
     refs = [row(b, n[b] + 1) for b in range(B)]
-    logits, new = jax.jit(decode_step, static_argnums=1)(
+    logits, new, _ = jax.jit(decode_step, static_argnums=1)(
         params, cfg, tokens[np.arange(B), n][:, None], caches,
         jnp.asarray(lens, jnp.int32))
 
@@ -178,6 +178,7 @@ def test_param_counts_sane():
         "mamba2-370m": (0.30e9, 0.45e9),
         "gemma3-1b": (0.8e9, 1.3e9),
         "whisper-base": (0.05e9, 0.11e9),
+        "deepseek-v2-lite": (15.5e9, 15.9e9),
     }
     from repro.models import count_params, count_params_analytic
     for arch, (lo, hi) in expect.items():
@@ -185,6 +186,6 @@ def test_param_counts_sane():
         assert lo <= n <= hi, f"{arch}: {n / 1e9:.2f}B not in [{lo/1e9}, {hi/1e9}]"
     # MoE active < total
     for arch in ("qwen3-moe-30b-a3b", "granite-moe-1b-a400m",
-                 "jamba-1.5-large-398b"):
+                 "jamba-1.5-large-398b", "deepseek-v2-lite"):
         cfg = get_config(arch)
         assert count_params_analytic(cfg, True) < count_params(cfg) * 0.6
