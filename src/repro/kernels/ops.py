@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import decode_attention as _da
 from repro.kernels import flash_attention as _fa
 from repro.kernels import rmsnorm as _rn
 from repro.kernels import ssd as _ssd
@@ -63,6 +64,39 @@ def flash_attention_bshd(q, k, v, *, causal: bool = True, block_q=128,
     o = flash_attention_diff(q.swapaxes(1, 2), k.swapaxes(1, 2),
                              v.swapaxes(1, 2), causal, block_q, block_k)
     return o.swapaxes(1, 2)
+
+
+def decode_block_rows(T: int, n_kv_heads: int, d_head: int, dtype) -> int:
+    """Cache rows per KV block of :func:`decode_attention` for this cache."""
+    itemsize = jnp.dtype(dtype).itemsize
+    return _da.block_rows(T, n_kv_heads, d_head, itemsize,
+                          _da.kv_order(n_kv_heads, d_head, itemsize))
+
+
+def decode_attention(q, cache_k, cache_v, lens, layer=None, *, window=None,
+                     softcap=None, scale=None):
+    """Ragged decode attention for model code.  q [B,1,H,D]; cache_[kv] the
+    layer stack [L,B,T,Hk,D] with ``layer`` its index, or one layer
+    [B,T,Hk,D] without; lens [B]: each slot's query position.  Returns
+    [B,1,H,D].
+
+    The stack is transposed to the order of axes the TPU keeps it in
+    (:func:`kernels.decode_attention.kv_order`), a bitcast there.  The
+    kernel runs in interpret mode where the program is compiled for the CPU
+    and as Mosaic elsewhere, chosen when the program is lowered."""
+    if layer is None:
+        cache_k, cache_v, layer = cache_k[None], cache_v[None], 0
+    B, _, H, D = q.shape
+    Hk = cache_k.shape[3]
+    order = _da.kv_order(Hk, D, cache_k.dtype.itemsize)
+    perm = (0, 1) + tuple(2 + "thd".index(a) for a in order)
+    call = functools.partial(_da.decode_attention, order=order, window=window,
+                             softcap=softcap, scale=scale)
+    out = jax.lax.platform_dependent(
+        q.reshape(B, Hk, H // Hk, D), jnp.transpose(cache_k, perm),
+        jnp.transpose(cache_v, perm), lens, layer,
+        cpu=functools.partial(call, interpret=True), default=call)
+    return out.reshape(B, 1, H, D)
 
 
 @jax.jit

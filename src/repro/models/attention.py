@@ -17,6 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ops import decode_attention
 from repro.models.layers import (apply_rope_bshd, rmsnorm, truncated_normal,
                                  yarn_mscale)
 from repro.models.scan_util import scan as _scan
@@ -281,7 +282,9 @@ def gqa_decode(params, x, cfg, cache_k, cache_v, lens, *, local: bool,
 
     ``cache_[kv]`` is the layer stack [L,B,T,Hk,D] with ``layer`` its index,
     or one layer [B,T,Hk,D] without; returns (out, cache_k, cache_v) with
-    the new K/V rows written at ``lens``."""
+    the new K/V rows written at ``lens``.  The query reads only its slot's
+    live, in-window rows, through the ragged kernel
+    (:func:`repro.kernels.ops.decode_attention`)."""
     q, k, v = gqa_project_qkv(params, x, cfg.n_heads, cfg.n_kv_heads, cfg.d_head)
     theta = cfg.rope_theta_local if (local and cfg.rope_theta_local) \
         else cfg.rope_theta
@@ -290,10 +293,9 @@ def gqa_decode(params, x, cfg, cache_k, cache_v, lens, *, local: bool,
     k = apply_rope_bshd(k, pos, theta)
     cache_k = cache_write(cache_k, k, lens, layer)
     cache_v = cache_write(cache_v, v, lens, layer)
-    window = cfg.attn_window if local else None
-    out = chunked_attention(
-        q, cache_layer(cache_k, layer), cache_layer(cache_v, layer),
-        causal=True, window=window, q_offset=lens, kv_valid_len=lens + 1,
+    out = decode_attention(
+        q, cache_k, cache_v, lens, layer,
+        window=cfg.attn_window if local else None,
         softcap=cfg.attn_logit_softcap)
     B = x.shape[0]
     return out.reshape(B, 1, -1) @ params["wo"], cache_k, cache_v

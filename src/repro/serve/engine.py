@@ -10,13 +10,15 @@ Each step opens the ``llload.serve.*`` profiler spans
 (``repro.monitor.SPAN_NAMES``); the device programs are ``jit_serve_prefill``
 and ``jit_serve_decode``.  For a model with expert layers the decode program
 also sums, on the device, the live tokens routed to each held expert; ``run``
-reads the sum once, when it returns.
+reads the sum once, when it returns.  For a model with GQA layers ``run``
+also reckons on the host, from each step's ``lens``, the cache rows the
+decode kernel fetches (``kv_rows_read_share``).
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import List, Optional
 
 import jax
@@ -26,6 +28,8 @@ from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core.overload import (DeviceObservation, OverloadController,
                                  OverloadDecision)
+from repro.kernels.decode_attention import kv_block_range
+from repro.kernels.ops import decode_block_rows
 from repro.monitor import publish_step_utilization
 from repro.models import model as model_lib
 from repro.roofline import hw
@@ -62,6 +66,31 @@ class EngineConfig:
     monitor: bool = True
 
 
+def kv_read_groups(cfg, T: int) -> Counter:
+    """{(block rows, window): layers} of the decode step's GQA layers, which
+    read the cache through the ragged kernel; empty for latent (MLA) and
+    state-space layers."""
+    if cfg.mla is not None:
+        return Counter()
+    kinds = ([cfg.layer_pattern[0]] * cfg.n_dense_layers
+             + list(cfg.layer_pattern) * cfg.n_periods
+             + list(cfg.layer_pattern[:cfg.n_remainder]))
+    blk = decode_block_rows(T, cfg.n_kv_heads, cfg.d_head, cfg.dtype)
+    return Counter((blk, cfg.attn_window if kind == "attn_local" else None)
+                   for kind in kinds if kind.startswith("attn"))
+
+
+def kv_rows_read(groups: Counter, lens, T: int) -> int:
+    """Cache rows the decode kernel fetches in one step at ``lens``, over
+    every layer of ``groups``: each slot's blocks of its [lo, hi) keys,
+    found by the index map's own :func:`kv_block_range`."""
+    rows = 0
+    for (blk, window), n_layers in groups.items():
+        first, last = kv_block_range(np.asarray(lens), T, blk, window)
+        rows += n_layers * blk * int(np.sum(last - first + 1))
+    return rows
+
+
 class ServeEngine:
     """Single-host engine; slots decode in lockstep with per-slot lengths."""
 
@@ -92,6 +121,7 @@ class ServeEngine:
         self._decode = jax.jit(serve_decode, donate_argnums=(2,))
         self._prefill = jax.jit(serve_prefill)
         self._flops_per_token = model_lib.model_flops(cfg, 1, training=False)
+        self._kv_groups = kv_read_groups(cfg, ecfg.max_seq_len)
 
     def submit(self, req: Request):
         req.submitted_s = time.perf_counter()
@@ -151,10 +181,12 @@ class ServeEngine:
     def run(self, *, max_steps: int = 10_000) -> dict:
         """Drain the queue.  Returns throughput stats, with this run's
         admissions and prompt tokens prefilled, and the mean duty cycle the
-        monitor hook published (None with monitoring off).  For a model
-        with expert layers, also the decode steps' live tokens routed to
-        each held expert, summed over the layers (``routed_per_expert``),
-        their mean and their max over the mean."""
+        monitor hook published (None with monitoring off), and the share
+        of the GQA layers' cache rows (L·B·T a step) that the decode steps'
+        kernel fetched (``kv_rows_read_share``; None without such layers or
+        steps).  For a model with expert layers, also the decode steps' live
+        tokens routed to each held expert, summed over the layers
+        (``routed_per_expert``), their mean and their max over the mean."""
         cfg, ecfg = self.cfg, self.ecfg
         B, T = ecfg.slots, ecfg.max_seq_len
         with TraceAnnotation("llload.serve.init"):
@@ -169,7 +201,7 @@ class ServeEngine:
         t_start = time.perf_counter()
         tokens_out = 0
         steps = 0
-        admitted = prefill_tokens = 0
+        admitted = prefill_tokens = kv_rows = 0
         duties = []
         while (self.queue or any(a is not None for a in active)) \
                 and steps < max_steps:
@@ -213,6 +245,7 @@ class ServeEngine:
 
                 n_active = sum(a is not None for a in active)
                 with TraceAnnotation("llload.serve.bookkeep"):
+                    kv_rows += kv_rows_read(self._kv_groups, lens, T)
                     for s in range(B):
                         if active[s] is None:
                             continue
@@ -243,6 +276,7 @@ class ServeEngine:
                     duties.append(util.duty_cycle)
 
         wall = time.perf_counter() - t_start
+        kv_layers = sum(self._kv_groups.values())
         moe = {}
         if routed is not None:
             per = np.asarray(routed, np.float64)
@@ -259,6 +293,8 @@ class ServeEngine:
             "wall_s": wall,
             "tokens_per_s": tokens_out / wall if wall > 0 else 0.0,
             "duty_mean": float(np.mean(duties)) if duties else None,
+            "kv_rows_read_share": (kv_rows / (steps * kv_layers * B * T)
+                                   if steps and kv_layers else None),
             "decision": self.controller.decide(ecfg.slots),
             **moe,
         }

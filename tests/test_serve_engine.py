@@ -88,3 +88,39 @@ def test_monitored_engine_needs_a_known_peak():
         ServeEngine(cfg, params, EngineConfig(monitor=True))
     eng = ServeEngine(cfg, params, EngineConfig(monitor=False))
     assert eng.peak_flops is None
+
+
+@pytest.mark.parametrize("arch,prompt_len,share", [
+    ("llsc-100m", 8, 0.5),        # every key in the first of two blocks
+    ("llsc-100m", 600, 1.0),      # every slot's keys span both blocks
+    ("gemma3-1b", 8, 0.5),        # local (window 8) and global layers
+])
+def test_kv_rows_read_share_is_the_kernels_blocks(arch, prompt_len, share):
+    """One slot, one request: the decode steps run at lens = P .. P + n - 2,
+    and the counter is the kernel's block range over those lens, a block of
+    512 rows in a cache of 1024."""
+    from repro.kernels.decode_attention import kv_block_range
+    from repro.serve.engine import kv_read_groups
+
+    T, n = 1024, 6
+    cfg, eng = _engine(arch, slots=1, max_seq=T)
+    eng.submit(_req(0, n=n, prompt_len=prompt_len))
+    stats = eng.run()
+    groups = kv_read_groups(cfg, T)
+    lens = np.arange(prompt_len, prompt_len + n - 1)
+    rows = 0
+    for (blk, window), n_layers in groups.items():
+        assert blk == 512
+        first, last = kv_block_range(lens, T, blk, window)
+        rows += n_layers * blk * int(np.sum(last - first + 1))
+    want = rows / (len(lens) * sum(groups.values()) * T)
+    assert stats["steps"] == len(lens)
+    assert stats["kv_rows_read_share"] == pytest.approx(want)
+    assert want == pytest.approx(share)
+
+
+def test_kv_rows_read_share_needs_gqa_layers():
+    """Latent attention reads its cache without the kernel: no share."""
+    _, eng = _engine("deepseek-v2-lite", slots=1)
+    eng.submit(_req(0, n=3))
+    assert eng.run()["kv_rows_read_share"] is None

@@ -113,15 +113,26 @@ def _phi3_medium_windowed_l2():
         layer_pattern=("attn_local",), mlp_pattern=("mlp",), attn_window=2047)
 
 
+def _phi3_mini_windowed_l2():
+    """32 KV heads of 96 (no GQA), every layer windowed, two layers."""
+    return dataclasses.replace(
+        get_config("phi3-medium-14b"), n_layers=2, d_model=3072, n_heads=32,
+        n_kv_heads=32, d_head=96, d_ff=8192, vocab_size=32064,
+        layer_pattern=("attn_local",), mlp_pattern=("mlp",), attn_window=2047)
+
+
 @pytest.mark.parametrize("make_cfg,B,T", [
     (lambda: get_config("llsc-100m"), 8, 2048),
     (_phi3_medium_windowed_l2, 16, 4096),
-], ids=["llsc-100m-8x2048", "phi3-medium-windowed-l2-16x4096"])
+    (_phi3_mini_windowed_l2, 32, 4096),
+], ids=["llsc-100m-8x2048", "phi3-medium-windowed-l2-16x4096",
+        "phi3-mini-windowed-l2-32x4096"])
 def test_decode_step_updates_cache_in_place(one_chip, make_cfg, B, T):
     """With the cache donated, as the engine donates it, the decode step
     writes its new rows into the cache's own buffer: the whole cache is
     aliased to the output and the step's scratch stays under one layer's
-    K cache, so no copy of a layer or of the stack is made."""
+    K cache, so no copy of a layer or of the stack is made.  The attention
+    reads the stack through the ragged kernel (a Mosaic custom call)."""
     cfg = make_cfg()
     on_chip = lambda s: _spec(one_chip, s.shape, s.dtype)  # noqa: E731
     params = jax.tree.map(on_chip, model_lib.init_params_shape(cfg))
@@ -139,3 +150,21 @@ def test_decode_step_updates_cache_in_place(one_chip, make_cfg, B, T):
     assert mem.temp_size_in_bytes < one_layer_k, (
         f"decode temp {mem.temp_size_in_bytes} B >= one layer's K "
         f"{one_layer_k} B: the step copies the cache")
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("Hk,D", [(32, 96), (10, 128), (12, 64), (4, 128),
+                                  (8, 128), (1, 256), (3, 128), (24, 128)])
+def test_kv_order_is_the_layout_the_chip_keeps(one_chip, Hk, D, dtype):
+    """The decode kernel takes the cache stack [L,B,T,Hk,D] transposed to
+    ``kv_order``; that is a bitcast only where it is the order of the
+    layout a v5e keeps for the stack."""
+    from repro.kernels.decode_attention import kv_order
+
+    cache = _spec(one_chip, (2, 4, 4096, Hk, D), dtype)
+    compiled = jax.jit(lambda c: c.at[0, 0, 0].set(1),
+                       donate_argnums=0).lower(cache).compile()
+    minor = compiled.input_formats[0][0].layout.major_to_minor[2:]
+    order = kv_order(Hk, D, jnp.dtype(dtype).itemsize)
+    assert minor == tuple(2 + "thd".index(a) for a in order)
